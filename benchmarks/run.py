@@ -71,6 +71,8 @@ def run_benches(benches, only: str | None, out_dir: str) -> list:
 
 
 def main() -> int:
+    from repro.launch.compilation import use_compile_cache
+    use_compile_cache()
     from benchmarks import (
         fig7_truncation_sweep, table2_memmode, table3_overhead,
         fig8_speedup_model, kernels_micro, perf_fp8_dot, roofline_table,

@@ -26,13 +26,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.apps.base import MiniApp, Observables
 
 
 def fp64_reference(app: MiniApp) -> Dict[str, np.ndarray]:
     """The app's full trajectory in float64: the oracle observables."""
-    with compat.enable_x64():
+    with jax.enable_x64(True):
         state = app.init_state(jnp.float64)
         obs = app.run_observables(state)
         return {k: np.asarray(jax.device_get(v), dtype=np.float64)

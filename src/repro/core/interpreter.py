@@ -449,13 +449,16 @@ def parameterized_callable(closed: jcore.ClosedJaxpr, out_tree,
     call. Either is compiled once per input signature — a new candidate
     policy is just a new table value.
 
-    With ``mesh`` the batched executable is GSPMD-partitioned: the leading
-    K (candidate) axis of ``tables`` is sharded over ``mesh.shape[batch_axis]``
-    devices — a W-candidate ladder evaluates on W/ndev devices concurrently —
-    while each candidate's ``(num_sites, 4)`` table rows stay replicated.
-    Profiled inputs follow ``flat_shardings`` (pre-resolved per-leaf, see
-    ``distributed.sharding.flatten_arg_shardings``; default replicated).
-    K must divide evenly across the axis — pad ladders with
+    With ``mesh`` the leading K (candidate) axis of ``tables`` is split
+    over ``mesh.shape[batch_axis]`` devices — a W-candidate ladder evaluates
+    on W/ndev devices concurrently — while each candidate's
+    ``(num_sites, 4)`` table rows stay whole. The split is a ``shard_map``
+    over ``batch_axis``, not GSPMD: each device runs its own candidates
+    through the unpartitioned program, since GSPMD cannot partition the
+    Pallas quantize kernels. Profiled inputs enter whole on that axis and
+    follow ``flat_shardings`` (pre-resolved per-leaf, see
+    ``distributed.sharding.flatten_arg_shardings``; default replicated) on
+    any other. K must divide evenly across the axis — pad ladders with
     ``index.identity_table()`` rows (``distributed.sharding.pad_to_shards``)
     and drop the padded outputs."""
     def _run(table, flat):
@@ -467,6 +470,8 @@ def parameterized_callable(closed: jcore.ClosedJaxpr, out_tree,
     if mesh is None and flat_shardings is None:
         return jax.jit(_run), jax.jit(vb)
 
+    from jax.sharding import PartitionSpec as P
+
     from repro.distributed.sharding import probe_sharding, replicated
 
     if mesh is not None:
@@ -474,6 +479,10 @@ def parameterized_callable(closed: jcore.ClosedJaxpr, out_tree,
                    else replicated(mesh))
         table_sh = probe_sharding(mesh, batch_axis)
         repl = replicated(mesh)
+        # check_vma off: pallas_call out_shapes carry no varying-axes info
+        vb = jax.shard_map(vb, mesh=mesh, in_specs=(P(batch_axis), P()),
+                           out_specs=P(batch_axis), axis_names={batch_axis},
+                           check_vma=False)
     else:  # concrete shardings given, no mesh for the table axis
         data_sh = flat_shardings
         table_sh = repl = None

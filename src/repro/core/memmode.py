@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax._src import core as jcore
+from jax._src import source_info_util
 
-from repro import compat
 from repro.core.policy import TruncationPolicy, join_stack
 from repro.kernels.quantize_em.ops import quantize
 
@@ -336,7 +336,7 @@ def eval_shadowed(jaxpr: jcore.Jaxpr, consts: Sequence[Any], args: Sequence[Any]
 
 def _loc_desc(eqn, prefix: str) -> str:
     ns = str(eqn.source_info.name_stack)
-    frame = compat.user_frame(eqn.source_info)
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
     src = f"{frame.file_name.split('/')[-1]}:{frame.start_line}" if frame else "?"
     scope = f"{prefix}/{ns}" if prefix and ns else (prefix or ns or "<root>")
     return f"{scope} {eqn.primitive.name} @ {src}"

@@ -33,21 +33,13 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-from repro import compat
-
-
-def best_mesh_shape(n_devices: int, model_parallel: int) -> Tuple[int, int]:
-    """Largest (data, model) grid for the currently visible devices."""
-    model = model_parallel
-    while model > 1 and n_devices % model:
-        model //= 2
-    return n_devices // model, model
+from repro.launch.mesh import best_mesh_shape, make_mesh
 
 
 def remesh(model_parallel: int = 16, axis_names=("data", "model")) -> Mesh:
     devs = jax.devices()
     data, model = best_mesh_shape(len(devs), model_parallel)
-    return compat.make_mesh((data, model), axis_names)
+    return make_mesh((data, model), axis_names)
 
 
 @dataclasses.dataclass

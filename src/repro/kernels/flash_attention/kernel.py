@@ -117,30 +117,22 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
     out_block = ((1, 1, block_q, Dv), lambda bh, g, qb: (bh, g, qb, 0))
     out_shape = jax.ShapeDtypeStruct((B * Hkv, G, S, Dv), q.dtype)
 
-    if out_fmt is None:
-        out = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[pl.BlockSpec(b, ix) for b, ix in in_blocks],
-            out_specs=pl.BlockSpec(*out_block),
-            out_shape=out_shape,
-            interpret=interpret,
-        )(qr, kr, vr)
-    else:
-        # index maps gain the trailing prefetch ref arg (unused for tiling)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec(b, lambda bh, g, qb, fmt, ix=ix: ix(bh, g, qb))
-                      for b, ix in in_blocks],
-            out_specs=pl.BlockSpec(
-                out_block[0],
-                lambda bh, g, qb, fmt, ix=out_block[1]: ix(bh, g, qb)),
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(jnp.asarray(out_fmt, jnp.int32), qr, kr, vr)
+    # index maps take the trailing prefetch ref when there is one
+    prefetch = () if out_fmt is None else (jnp.asarray(out_fmt, jnp.int32),)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=grid,
+        in_specs=[pl.BlockSpec(b, lambda bh, g, qb, *_, ix=ix: ix(bh, g, qb))
+                  for b, ix in in_blocks],
+        out_specs=pl.BlockSpec(
+            out_block[0],
+            lambda bh, g, qb, *_, ix=out_block[1]: ix(bh, g, qb)),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        name="flash_attention",
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*prefetch, qr, kr, vr)
     return out.reshape(B, Hq, S, Dv)

@@ -25,12 +25,12 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-# kernel-name marker -> output indices covered by the in-kernel epilogue
+# pallas_call ``name`` -> output indices covered by the in-kernel epilogue
 # (other outputs — e.g. rwkv6's recurrence state sT — are ordinary sites
 # and keep the separate quantize pass)
 FUSED_KERNELS = {
-    "_attn_kernel": (0,),
-    "_wkv_kernel": (0,),
+    "flash_attention": (0,),
+    "wkv6": (0,),
 }
 
 _ROW_SHAPE = (4,)
@@ -42,7 +42,7 @@ def fused_outputs(eqn) -> Optional[Tuple[int, ...]]:
     Recognition is structural: a ``pallas_call`` whose grid mapping
     prefetches exactly one scalar operand, whose first operand is a (4,)
     int32 row, and whose kernel is one of the known epilogue-bearing
-    kernels (by ``name_and_src_info``)."""
+    kernels (by the ``name`` the kernel module gives its ``pallas_call``)."""
     if eqn.primitive.name != "pallas_call":
         return None
     gm = eqn.params.get("grid_mapping")
@@ -52,9 +52,4 @@ def fused_outputs(eqn) -> Optional[Tuple[int, ...]]:
     if (getattr(aval, "shape", None) != _ROW_SHAPE
             or getattr(aval, "dtype", None) != np.dtype(np.int32)):
         return None
-    info = eqn.params.get("name_and_src_info")
-    kname = getattr(info, "name", None) or str(info)
-    for marker, outs in FUSED_KERNELS.items():
-        if marker in kname:
-            return outs
-    return None
+    return FUSED_KERNELS.get(eqn.params.get("name"))

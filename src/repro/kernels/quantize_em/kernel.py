@@ -6,9 +6,12 @@ VPU rate. The kernel is pure elementwise integer bit manipulation on
 ``(8,128)``-aligned VMEM tiles — no MXU, no transcendentals, one pass.
 
 Target layout: input flattened to (rows, 1024) f32, grid over row-blocks,
-each block (block_rows, 1024) resident in VMEM (4 MiB in + 4 MiB out at the
-default block_rows=1024 — comfortably inside the ~128 MiB v5e VMEM even with
-double buffering).
+each block (block_rows, 1024) resident in VMEM. What bounds the block is
+the compiler's default *scoped* VMEM limit (16 MiB on v5e), not the
+physical VMEM: in and out are double-buffered and the elementwise math
+keeps block-sized temporaries live. At block_rows=1024 (4 MiB per block)
+the v5e compile asks for a 32 MiB scoped allocation and is refused; 512
+rows (2 MiB per block) compiles for both kernels (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -24,6 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.quantize_em import ref as _ref
 
 LANES = 1024  # 8 * 128 lane multiple
+BLOCK_ROWS = 512  # largest power of two that fits v5e's scoped VMEM
 
 
 def _quantize_block(x, *, exp_bits: int, man_bits: int, saturate: bool,
@@ -86,7 +90,7 @@ def _kernel(x_ref, o_ref, *, exp_bits, man_bits, saturate, ieee_inf):
                      "block_rows", "interpret"),
 )
 def quantize_2d(x, *, exp_bits: int, man_bits: int, saturate: bool = False,
-                ieee_inf: bool = True, block_rows: int = 1024,
+                ieee_inf: bool = True, block_rows: int = BLOCK_ROWS,
                 interpret: bool = False):
     """Quantize a (rows, LANES) f32 array on the (e,m) grid via pallas_call."""
     assert x.ndim == 2 and x.shape[1] == LANES, x.shape
@@ -100,6 +104,7 @@ def quantize_2d(x, *, exp_bits: int, man_bits: int, saturate: bool = False,
         in_specs=[pl.BlockSpec((br, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="quantize_em",
         interpret=interpret,
     )(x)
 
@@ -109,20 +114,19 @@ def quantize_2d(x, *, exp_bits: int, man_bits: int, saturate: bool = False,
 # ---------------------------------------------------------------------------
 
 def _dyn_kernel(fmt_ref, x_ref, o_ref):
-    """``fmt_ref`` is the scalar-prefetched (4,) int32 format vector
-    (exp_bits, man_bits, saturate, ieee_inf) living in SMEM; the block math
-    is the shared traced-scalar path (pure bit ops + where gates, f32 only),
-    so one compiled kernel serves every format."""
-    o_ref[...] = _ref.quantize_ref_dynamic(
-        x_ref[...], fmt_ref[0], fmt_ref[1], fmt_ref[2], fmt_ref[3])
+    """``fmt_ref`` is the scalar-prefetched (4,) int32 format row
+    (exp_bits, man_bits, saturate, ieee_inf | fault << 1) living in SMEM;
+    the block math is the fused kernels' epilogue (pure bit ops + where
+    gates, f32 only), so one compiled kernel serves every format."""
+    o_ref[...] = _ref.quantize_epilogue(x_ref[...], fmt_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def quantize_2d_dynamic(x, fmt, *, block_rows: int = 1024,
+def quantize_2d_dynamic(x, fmt, *, block_rows: int = BLOCK_ROWS,
                         interpret: bool = False):
     """Quantize a (rows, LANES) f32 array onto the grid described by the
-    runtime (4,) int32 vector ``fmt`` — same layout/grid as ``quantize_2d``
-    but compiled once for all formats."""
+    runtime (4,) int32 row ``fmt`` (fault channel included) — same
+    layout/grid as ``quantize_2d`` but compiled once for all formats."""
     assert x.ndim == 2 and x.shape[1] == LANES, x.shape
     rows = x.shape[0]
     br = min(block_rows, rows)
@@ -136,5 +140,6 @@ def quantize_2d_dynamic(x, fmt, *, block_rows: int = 1024,
         _dyn_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="quantize_em_dynamic",
         interpret=interpret,
     )(jnp.asarray(fmt, jnp.int32), x)

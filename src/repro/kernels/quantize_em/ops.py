@@ -2,7 +2,7 @@
 
 ``impl``:
   * ``'auto'``      — pallas on TPU, pure-jnp ref elsewhere (CPU dry-runs must
-                      not lower pallas kernels; see DESIGN.md §4)
+                      not lower pallas kernels)
   * ``'ref'``       — pure-jnp oracle
   * ``'pallas'``    — compiled pallas kernel (TPU)
   * ``'interpret'`` — pallas kernel in interpret mode (CPU validation)
@@ -85,22 +85,6 @@ def quantize(x, fmt, *, impl: str = "auto"):
     return y.astype(dt)
 
 
-def _bitflip(y, fault):
-    """XOR bit ``fault - 1`` into every element's carrier bit pattern.
-
-    ``fault == 0`` is an exact no-op (the XOR mask is zero), so unarmed rows
-    are bit-identical to a quantizer without the fault channel. The bit
-    index addresses the carrier layout: f32 for <=32-bit floats (31 = sign,
-    30 = top exponent bit), f64 for f64 inputs."""
-    itype = jnp.int64 if y.dtype == jnp.dtype(jnp.float64) else jnp.int32
-    shift = jnp.maximum(fault - 1, 0).astype(itype)
-    mask = jnp.where(fault > 0,
-                     jnp.left_shift(jnp.asarray(1, itype), shift),
-                     jnp.asarray(0, itype))
-    bits = jax.lax.bitcast_convert_type(y, itype)
-    return jax.lax.bitcast_convert_type(bits ^ mask, y.dtype)
-
-
 def quantize_dynamic(x, fmt, *, impl: str = "auto"):
     """Runtime-parameterized ``quantize``: ``fmt`` is a (4,) int32 array
     (exp_bits, man_bits, saturate, ieee_inf) whose values are *runtime* data
@@ -117,12 +101,12 @@ def quantize_dynamic(x, fmt, *, impl: str = "auto"):
 
     **Fault channel** (``repro.guardrails.faults``): the high bits of the
     row's fourth field carry an optional bit-flip fault,
-    ``field3 = ieee_inf | (bit_index + 1) << 1``. The channel is decoded and
-    stripped here — the quantizer impls always see a clean {0, 1} flag —
-    then the chosen carrier bit is XORed into every (already quantized)
-    element. A clean row (field3 in {0, 1}) decodes to fault 0 and the XOR
-    is an exact no-op, so arming or disarming a fault is a table *value*
-    change on the same compiled executable: zero recompiles."""
+    ``field3 = ieee_inf | (bit_index + 1) << 1``. Every impl decodes it
+    (the Pallas kernel in-kernel) and XORs the chosen carrier bit into every
+    (already quantized) element. A clean row (field3 in {0, 1}) decodes to
+    fault 0 and the XOR is an exact no-op, so arming or disarming a fault is
+    a table *value* change on the same compiled executable: zero
+    recompiles."""
     dt = jnp.dtype(x.dtype) if hasattr(x, "dtype") else None
     if dt is None or not jnp.issubdtype(dt, jnp.floating):
         return x
@@ -149,9 +133,9 @@ def quantize_dynamic(x, fmt, *, impl: str = "auto"):
         p = _ref.dynamic_row_params(e, m, s, inf, fault)
         return _ref.apply_row_params(xf, p).astype(dt)
     if impl in ("pallas", "interpret"):
-        y = _pallas_any_shape_dynamic(xf, jnp.stack([e, m, s, inf]),
-                                      interpret=(impl == "interpret"))
-        return _bitflip(y, fault).astype(dt)
+        # the kernel decodes the fault channel itself (quantize_epilogue)
+        y = _pallas_any_shape_dynamic(xf, fmt, interpret=(impl == "interpret"))
+        return y.astype(dt)
     raise ValueError(f"unknown impl {impl!r}")
 
 

@@ -13,8 +13,7 @@ Semantics (validated against ml_dtypes for every hardware format in tests):
   * NaN preserved, +/-inf preserved, +/-0 preserved
 Known carrier-precision floor: inputs that are subnormal *in the carrier*
 combined with a target whose exponent range exceeds the carrier's cannot be
-re-normalized (DESIGN.md §7); irrelevant for every profiling configuration
-in this repo.
+re-normalized; irrelevant for every profiling configuration in this repo.
 """
 from __future__ import annotations
 
@@ -109,13 +108,13 @@ def quantize_ref_fmt(x, fmt):
 #
 # ``quantize_ref`` above specializes the computation on the format at trace
 # time (python branches, numpy constants), so every distinct format costs a
-# retrace + recompile. ``quantize_ref_dynamic`` takes the format fields as
-# *traced scalars*: one compiled executable serves every (e, m, saturate,
-# ieee_inf), which is what collapses a precision-policy sweep to a single
-# XLA compilation. All static branches become lane-wise ``where`` gates; the
-# static identity fast path becomes the in-kernel ``man_bits >= carrier``
-# gate. Kept free of python-level f64 branches when the carrier is f32 so
-# the Pallas kernel can call it directly.
+# retrace + recompile. ``dynamic_row_params`` + ``apply_row_params`` take
+# the format fields as *traced values*: one compiled executable serves every
+# (e, m, saturate, ieee_inf), which is what collapses a precision-policy
+# sweep to a single XLA compilation. All static branches become lane-wise
+# ``where`` gates; the static identity fast path becomes the in-kernel
+# ``man_bits >= carrier`` gate. Kept free of python-level f64 branches when
+# the carrier is f32 so the Pallas kernels can call them directly.
 
 
 def _pow2(n, dt):
@@ -136,7 +135,7 @@ def dynamic_row_params(exp_bits, man_bits, saturate, ieee_inf, fault=0,
                        dtype=jnp.float32):
     """Derived rounding constants for the runtime quantizer, elementwise.
 
-    Every quantity ``quantize_ref_dynamic`` derives from the format fields —
+    Every quantity the runtime quantizer derives from the format fields —
     rounding masks, range bounds, gates, the fault XOR mask — but none of
     the array-side math. Inputs may be python ints, traced scalars, or
     whole ``(num_sites,)`` table columns: the math is elementwise, so one
@@ -178,10 +177,11 @@ def dynamic_row_params(exp_bits, man_bits, saturate, ieee_inf, fault=0,
     use_sub = (e < c_exp) & (sub_scale >= tiny)
     ss = jnp.where(use_sub, sub_scale, np.array(1.0, dt))
     # exact reciprocal: ss is a power of two >= the carrier's tiny, so 1/ss
-    # is finite and x * (1/ss) == x / ss bit-for-bit. Multiplying is much
-    # cheaper for XLA's CPU backend to compile than the division (the
-    # subnormal section dominated the swept executable's compile time).
-    ssinv = jnp.where(use_sub, np.array(1.0, dt) / ss, np.array(1.0, dt))
+    # = 2**(m - min_exp) is finite and x * (1/ss) == x / ss bit-for-bit.
+    # Built as a power of two rather than divided: a TPU vector divide is
+    # not guaranteed correctly rounded, and the multiply is much cheaper
+    # for XLA's CPU backend to compile than the division.
+    ssinv = jnp.where(use_sub, _pow2(m - min_exp, dt), np.array(1.0, dt))
     ovf_gate = max_finite <= np.array(finfo.max, dt)
     # overflow magnitude for the sign-carrying cases; the fn (overflow->NaN)
     # case is selected separately in apply so the stored NaN stays the
@@ -232,21 +232,6 @@ def apply_row_params(x, p):
     return lax.bitcast_convert_type(jnp.bitwise_xor(yb, p["fmask"]), dt)
 
 
-def quantize_ref_dynamic(x, exp_bits, man_bits, saturate, ieee_inf):
-    """Quantize carrier array ``x`` (f32/f64) onto the (e, m) grid where the
-    format fields are *runtime* scalars (python ints or traced int32).
-
-    Bit-for-bit identical to ``quantize_ref`` for any format whose mantissa
-    fits the carrier (``man_bits <= nmant``); formats at least as fine as the
-    carrier grid (and with IEEE overflow) are returned unchanged via the
-    in-kernel identity gate."""
-    dt = jnp.dtype(x.dtype)
-    if dt not in _CARRIER:
-        raise TypeError(f"carrier must be f32/f64, got {dt}")
-    p = dynamic_row_params(exp_bits, man_bits, saturate, ieee_inf, dtype=dt)
-    return apply_row_params(x, p)
-
-
 # ---------------------------------------------------------------------------
 # fused-kernel epilogue: the full runtime row, applied to a store value
 # ---------------------------------------------------------------------------
@@ -261,20 +246,8 @@ def quantize_ref_dynamic(x, exp_bits, man_bits, saturate, ieee_inf):
 # (kernels must stay importable before repro.core finishes initializing).
 
 
-def bitflip32(y, fault):
-    """XOR bit ``fault - 1`` into each element's f32 bit pattern; ``fault == 0``
-    is an exact no-op. The in-kernel (f32-only) twin of ``ops._bitflip``."""
-    fault = jnp.asarray(fault, jnp.int32)
-    shift = jnp.maximum(fault - 1, 0)
-    mask = jnp.where(fault > 0,
-                     jnp.left_shift(jnp.asarray(1, jnp.int32), shift),
-                     jnp.asarray(0, jnp.int32))
-    bits = lax.bitcast_convert_type(y, jnp.int32)
-    return lax.bitcast_convert_type(jnp.bitwise_xor(bits, mask), jnp.float32)
-
-
 def quantize_epilogue(y, fmt_ref):
-    """Apply a runtime format row to kernel output ``y`` just before the
+    """Apply a runtime format row to 2-D kernel output ``y`` just before the
     store: decode ``field3 = ieee_inf | (bit_index + 1) << 1``, quantize the
     f32 carrier, XOR the armed fault bit, cast back to ``y.dtype``.
 
@@ -283,8 +256,13 @@ def quantize_epilogue(y, fmt_ref):
     Bit-identical to ``ops.quantize_dynamic(y, row, impl='ref')`` for any
     f32-carrier ``y``; the identity row (and any clean row with fault 0)
     passes values through unchanged, so the fused kernels can always run
-    with the epilogue wired in."""
-    e, m, s, f3 = fmt_ref[0], fmt_ref[1], fmt_ref[2], fmt_ref[3]
+    with the epilogue wired in. The standalone runtime quantize kernel is
+    this epilogue on a lane-dense block."""
+    # Mosaic lowers bitcasts (``_pow2``) and float math on vectors only, so
+    # the row constants are derived on one (1, lanes) broadcast row rather
+    # than on SMEM scalars; apply_row_params broadcasts it down the sublanes
+    e, m, s, f3 = (jnp.full((1, y.shape[-1]), fmt_ref[i], jnp.int32)
+                   for i in range(4))
     fault = jnp.right_shift(f3, 1)
     inf = jnp.bitwise_and(f3, 1)
     p = dynamic_row_params(e, m, s, inf, fault)

@@ -4,11 +4,12 @@ Per head h with per-token, per-channel decay w_t in (0,1):
     S_t = diag(w_t) S_{t-1} + k_t^T v_t
     y_t = r_t (S_{t-1} + diag(u) k_t^T v_t)
 
-Grid = (batch*heads,); each program owns one head's full sequence, scanning
-chunks of length C resident in VMEM. Within a chunk the recurrence is the
-exact step form (fori_loop over C tokens — numerically identical to the
-reference; the decay products stay implicit so no 1/A overflow issues), and
-only the (hd x hd) state crosses chunk boundaries. This is the TPU analogue
+Grid = (batch*heads, chunks); the chunk axis runs in order, streaming one
+chunk of length C per program through VMEM while the (hd x hd) state stays
+in a VMEM scratch across the chunk axis (so VMEM use does not grow with the
+sequence). Within a chunk the recurrence is the exact step form (fori_loop
+over C tokens — numerically identical to the reference; the decay products
+stay implicit so no 1/A overflow issues). This is the TPU analogue
 of the within-chunk/cross-chunk split used by GPU linear-attention kernels,
 re-blocked for VMEM instead of shared memory.
 
@@ -27,48 +28,43 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.quantize_em import ref as _qref
 
 
-def _wkv_kernel(*refs, chunk: int, seq_len: int, quantized: bool = False):
+def _wkv_kernel(*refs, chunk: int, quantized: bool = False):
     if quantized:
         # fused epilogue: (4,) int32 runtime format row via SMEM scalar
         # prefetch, applied to the per-chunk y stores (the recurrence state
         # sT is a carry, not a truncation site — it stays exact)
         (fmt_ref, r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
-         y_ref, sT_ref) = refs
+         y_ref, sT_ref, s_ref) = refs
     else:
-        r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref = refs
-    hd = r_ref.shape[-1]
-    u = u_ref[0].astype(jnp.float32)                       # (hd,)
-    nch = seq_len // chunk
+        r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_ref = refs
+    c = pl.program_id(1)
 
-    def chunk_body(c, state):
-        r = r_ref[0, c].astype(jnp.float32)                # (C, hd)
-        k = k_ref[0, c].astype(jnp.float32)
-        v = v_ref[0, c].astype(jnp.float32)
-        w = w_ref[0, c].astype(jnp.float32)
+    @pl.when(c == 0)
+    def _():
+        s_ref[...] = s0_ref[0].astype(jnp.float32)
 
-        def tok(t, carry):
-            s, y = carry
-            kt = jax.lax.dynamic_slice_in_dim(k, t, 1, 0)  # (1, hd)
-            vt = jax.lax.dynamic_slice_in_dim(v, t, 1, 0)
-            rt = jax.lax.dynamic_slice_in_dim(r, t, 1, 0)
-            wt = jax.lax.dynamic_slice_in_dim(w, t, 1, 0)
-            kv = kt.T @ vt                                 # (hd, hd)
-            yt = rt @ (s + u[:, None] * kv)                # (1, hd)
-            s = wt.T * s + kv
-            y = jax.lax.dynamic_update_slice_in_dim(y, yt, t, 0)
-            return s, y
+    u = u_ref[0].astype(jnp.float32)                       # (1, hd)
 
-        y0 = jnp.zeros((chunk, hd), jnp.float32)
-        state, y = jax.lax.fori_loop(0, chunk, tok, (state, y0))
-        y = y.astype(y_ref.dtype)
-        if quantized:
-            y = _qref.quantize_epilogue(y, fmt_ref)
-        y_ref[0, c] = y
-        return state
+    def tok(t, s):
+        # per-token rows are loaded from and stored to the refs: Mosaic has
+        # no dynamic_slice / dynamic_update_slice on values
+        row = (0, 0, pl.ds(t, 1), slice(None))
+        kt = k_ref[row].astype(jnp.float32)                # (1, hd)
+        vt = v_ref[row].astype(jnp.float32)
+        rt = r_ref[row].astype(jnp.float32)
+        wt = w_ref[row].astype(jnp.float32)
+        kv = kt.T @ vt                                     # (hd, hd)
+        ukv = (u * kt).T @ vt                              # diag(u) kv
+        y_ref[row] = (rt @ (s + ukv)).astype(y_ref.dtype)
+        return wt.T * s + kv
 
-    state = s0_ref[0].astype(jnp.float32)
-    state = jax.lax.fori_loop(0, nch, chunk_body, state)
-    sT_ref[0] = state.astype(sT_ref.dtype)
+    s_ref[...] = jax.lax.fori_loop(0, chunk, tok, s_ref[...])
+    if quantized:
+        y_ref[0, 0] = _qref.quantize_epilogue(y_ref[0, 0], fmt_ref)
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _():
+        sT_ref[0] = s_ref[...].astype(sT_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -89,52 +85,35 @@ def wkv6_pallas(r, k, v, w, u, s0, *, chunk: int = 64,
     def shape4(t):
         return t.reshape(B * H, nch, chunk, hd)
 
-    u_r = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, hd)
+    # (B*H, 1, hd): a block's last two dims must tile (8, 128) or span the
+    # array, so the per-head bonus row keeps a unit sublane axis
+    u_r = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, 1, hd)
     s0_r = s0.reshape(B * H, hd, hd)
 
-    grid = (B * H,)
-    kernel = functools.partial(_wkv_kernel, chunk=chunk, seq_len=S,
-                               quantized=out_fmt is not None)
-    in_blocks = [
-        ((1, nch, chunk, hd), lambda i: (i, 0, 0, 0)),
-        ((1, nch, chunk, hd), lambda i: (i, 0, 0, 0)),
-        ((1, nch, chunk, hd), lambda i: (i, 0, 0, 0)),
-        ((1, nch, chunk, hd), lambda i: (i, 0, 0, 0)),
-        ((1, hd), lambda i: (i, 0)),
-        ((1, hd, hd), lambda i: (i, 0, 0)),
-    ]
-    out_blocks = [
-        ((1, nch, chunk, hd), lambda i: (i, 0, 0, 0)),
-        ((1, hd, hd), lambda i: (i, 0, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B * H, nch, chunk, hd), jnp.float32),
-        jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32),
-    ]
-    operands = (shape4(r), shape4(k), shape4(v), shape4(w), u_r, s0_r)
+    def spec(block, ix):
+        # index maps take the trailing prefetch ref when there is one
+        return pl.BlockSpec(block, lambda i, c, *_: ix(i, c))
 
-    if out_fmt is None:
-        y, sT = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[pl.BlockSpec(b, ix) for b, ix in in_blocks],
-            out_specs=[pl.BlockSpec(b, ix) for b, ix in out_blocks],
-            out_shape=out_shape,
-            interpret=interpret,
-        )(*operands)
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec(b, lambda i, fmt, ix=ix: ix(i))
-                      for b, ix in in_blocks],
-            out_specs=[pl.BlockSpec(b, lambda i, fmt, ix=ix: ix(i))
-                       for b, ix in out_blocks],
-        )
-        y, sT = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(jnp.asarray(out_fmt, jnp.int32), *operands)
+    seq = ((1, 1, chunk, hd), lambda i, c: (i, c, 0, 0))
+    head = ((1, hd, hd), lambda i, c: (i, 0, 0))
+    prefetch = () if out_fmt is None else (jnp.asarray(out_fmt, jnp.int32),)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B * H, nch),
+        in_specs=[spec(*seq)] * 4 + [
+            spec((1, 1, hd), lambda i, c: (i, 0, 0)), spec(*head)],
+        out_specs=[spec(*seq), spec(*head)],
+        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
+    )
+    y, sT = pl.pallas_call(
+        functools.partial(_wkv_kernel, chunk=chunk,
+                          quantized=out_fmt is not None),
+        grid_spec=grid_spec,
+        name="wkv6",
+        out_shape=[
+            jax.ShapeDtypeStruct((B * H, nch, chunk, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*prefetch, shape4(r), shape4(k), shape4(v), shape4(w), u_r, s0_r)
     return y.reshape(B, H, S, hd), sT.reshape(B, H, hd, hd)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: for each cell
@@ -16,6 +13,7 @@ Usage:
 Artifacts land in experiments/dryrun/<arch>__<shape>__<mesh>.json.
 """
 import argparse
+import os
 import json
 import re
 import time
@@ -36,6 +34,8 @@ from repro.launch import specs as sp
 from repro.models import Model
 from repro.train.trainer import TrainConfig, make_train_step
 from repro.optim.adamw import AdamWConfig
+
+_POD_CHIPS = 256  # one v5e pod slice
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
@@ -150,7 +150,8 @@ def _serve_rules(model: Model):
 def lower_cell(arch_id: str, shape: InputShape, multi_pod: bool):
     cfg = get_config(arch_id)
     model = Model(cfg)
-    mesh = make_production_mesh(multi_pod=multi_pod)
+    mesh = make_production_mesh(
+        multi_pod=multi_pod, n_devices=_POD_CHIPS * (2 if multi_pod else 1))
     param_rules = (None if shape.kind == "train" else _serve_rules(model))
     with shd.use_mesh(mesh, param_rules=param_rules):
         if shape.kind == "train":
@@ -185,7 +186,7 @@ def lower_cell(arch_id: str, shape: InputShape, multi_pod: bool):
 
 def jaxpr_counts(fn, args):
     """Global FLOP/byte totals with scan trip counts folded in (XLA's
-    cost_analysis counts while-loop bodies once — see DESIGN.md). Returns
+    cost_analysis counts while-loop bodies once). Returns
     (flops, bytes_unfused, bytes_fused, scope_census): the census is the
     precision-search work-list for this cell — the ``named_scope`` frontier
     ``repro.search.autosearch`` would assign formats to, with FLOP shares."""
@@ -267,6 +268,11 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    # fake CPU devices for the pod meshes: set before JAX first looks for
+    # devices, and appended so any flags the user set survive
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        f"--xla_force_host_platform_device_count={2 * _POD_CHIPS}")))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
@@ -285,7 +291,7 @@ def main():
                 continue
             if not runnable:
                 print(f"[SKIP] {arch}__{shape.name} — full-attention arch, "
-                      f"long-context cell skipped per DESIGN.md §5", flush=True)
+                      "long-context cell skipped", flush=True)
                 continue
             meshes = []
             if not args.multi_pod_only:

@@ -1,33 +1,67 @@
-"""Production mesh construction.
+"""Mesh construction from the devices present.
 
-Single pod: (data=16, model=16) = 256 chips (TPU v5e pod slice).
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the leading ``pod`` axis
-is pure data parallelism across pods (slow DCI links carry only the gradient
-all-reduce; params/optimizer are FSDP-sharded within a pod).
+The production mesh puts model (tensor) parallelism inside a pod-slice row
+of at most 16 chips and data parallelism across the rest: a 256-chip v5e
+pod slice is (data=16, model=16) and one chip is (1, 1). Multi-pod adds a
+leading ``pod`` axis of pure data parallelism across pods (slow DCI links
+carry only the gradient all-reduce; params/optimizer are FSDP-sharded
+within a pod): two pods are (pod=2, data=16, model=16).
 
-A FUNCTION, not a module constant: importing this module must never touch
-jax device state (the dry-run sets XLA_FLAGS before first jax init).
+FUNCTIONS, not module constants: importing this module must never touch
+jax device state.
 """
 from __future__ import annotations
 
+from typing import Tuple
+
 import jax
+from jax.sharding import AxisType
 
-from repro import compat
+POD_MODEL_PARALLEL = 16
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the repo places arrays
+    through sharding rules and GSPMD, while ``jax.make_mesh`` defaults to
+    explicit-sharding axes."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
+
+
+def best_mesh_shape(n_devices: int, model_parallel: int) -> Tuple[int, int]:
+    """Largest (data, model) grid for ``n_devices``: the model axis is the
+    widest power-of-two fraction of ``model_parallel`` that divides it."""
+    model = model_parallel
+    while model > 1 and n_devices % model:
+        model //= 2
+    return n_devices // model, model
+
+
+def make_production_mesh(*, multi_pod: bool = False,
+                         n_devices: int | None = None):
+    """(data, model) — or (pod, data, model) — mesh over the first
+    ``n_devices`` devices present (default: all of them)."""
+    devs = jax.devices()
+    n = len(devs) if n_devices is None else n_devices
+    if n > len(devs):
+        raise ValueError(f"make_production_mesh: {n} devices requested, "
+                         f"{len(devs)} visible")
+    pods = 2 if multi_pod else 1
+    if n % pods:
+        raise ValueError(f"{n} devices do not split into {pods} pods")
+    shape: Tuple[int, ...] = best_mesh_shape(n // pods, POD_MODEL_PARALLEL)
+    axes: Tuple[str, ...] = ("data", "model")
+    if multi_pod:
+        shape, axes = (pods, *shape), ("pod", *axes)
+    return make_mesh(shape, axes, devices=devs[:n])
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU runs)."""
+    """Small (data, model) mesh over whatever devices exist (tests / CPU
+    runs)."""
     n = len(jax.devices())
-    mp = model_parallel
-    while mp > 1 and n % mp:
-        mp //= 2
-    return compat.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh(best_mesh_shape(n, model_parallel), ("data", "model"))
 
 
 def make_probe_mesh(n_devices: int | None = None, axis: str = "probe"):
@@ -47,7 +81,7 @@ def make_probe_mesh(n_devices: int | None = None, axis: str = "probe"):
                 f"make_probe_mesh: {n_devices} devices requested, "
                 f"{len(devs)} visible")
         devs = devs[:n_devices]
-    return compat.make_mesh((len(devs),), (axis,), devices=devs)
+    return make_mesh((len(devs),), (axis,), devices=devs)
 
 
 def make_profile_mesh(probe: int, data: int = 1, *,
@@ -59,4 +93,4 @@ def make_profile_mesh(probe: int, data: int = 1, *,
     if n > len(devs):
         raise ValueError(f"make_profile_mesh: {n} devices requested, "
                          f"{len(devs)} visible")
-    return compat.make_mesh((probe, data), tuple(axes), devices=devs[:n])
+    return make_mesh((probe, data), tuple(axes), devices=devs[:n])

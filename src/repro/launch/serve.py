@@ -3,7 +3,11 @@ truncation policy and sampled shadow profiling of live traffic.
 
     PYTHONPATH=src python -m repro.launch.serve --arch glm4-9b \
         [--policy "scope:**/mlp=fp16"] [--requests 8] [--new-tokens 16] \
-        [--shadow-rate 0.0625] [--drift-margin 4.0]
+        [--shadow-rate 0.0625] [--drift-margin 4.0] [--production]
+
+``--production`` serves the full-size config on a mesh built from the
+devices present (one v5e chip holds h2o-danube-1.8b whole); the default
+``--smoke`` config is the reduced one.
 
 Requests stream in with mixed prompt lengths and token budgets; the
 engine admits each one into any free decode slot while the other slots
@@ -37,6 +41,7 @@ from repro.artifacts import default_root
 from repro.configs.base import get_config
 from repro.core.policy import resolve_policy as _core_resolve_policy
 from repro.distributed import sharding as shd
+from repro.launch.compilation import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import Model
 from repro.models.common import ParamDef
@@ -91,12 +96,17 @@ def main():
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--production", dest="smoke", action="store_false")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, "smoke" if args.smoke else "full")
     model = Model(cfg)
-    # serving uses TP-only params when they fit (DESIGN.md §6 / §Perf it.6)
+    # serving uses TP-only params (SERVE_PARAM_RULES)
     mesh = (make_host_mesh(model_parallel=2) if args.smoke
             else make_production_mesh())
+    print(f"arch={cfg.name} layers={cfg.n_layers} d_model={cfg.d_model} "
+          f"params={model.n_params() / 1e6:.1f}M mesh={dict(mesh.shape)} "
+          f"devices={mesh.devices.flat[0].device_kind} x{mesh.size}",
+          flush=True)
     with shd.use_mesh(mesh, param_rules=shd.SERVE_PARAM_RULES):
         defs = model.param_defs()
         sh = jax.tree_util.tree_map(

@@ -1,10 +1,11 @@
 """Production training entrypoint.
 
 On a real fleet each host runs:
-    python -m repro.launch.train --arch glm4-9b --steps 100000 \
+    python -m repro.launch.train --arch glm4-9b --steps 100000 --production \
         --ckpt gs://bucket/run1 [--coordinator host:port --num-hosts N]
-and the same command on this CPU container runs the identical code path on
-the host mesh with a smoke-scaled config (--smoke, default here).
+``--production`` trains the full-size config on a mesh built from the
+devices present (a pod slice, or one chip); the default ``--smoke`` runs
+the identical code path with a reduced config on the host mesh.
 
 Covers the large-scale-runnability contract end-to-end: distributed init,
 production mesh, FSDP×TP param placement, deterministic host-sharded data,
@@ -37,6 +38,7 @@ from repro.distributed import sharding as shd
 from repro.distributed.fault_tolerance import (
     StragglerMonitor, SupervisorConfig, run_supervised,
 )
+from repro.launch.compilation import use_compile_cache
 from repro.launch.mesh import make_production_mesh, make_host_mesh
 from repro.models import Model
 from repro.models.common import ParamDef
@@ -95,6 +97,7 @@ def main():
     ap.add_argument("--num-hosts", type=int, default=1)
     ap.add_argument("--host-id", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.coordinator:
         jax.distributed.initialize(args.coordinator, args.num_hosts,
@@ -108,6 +111,7 @@ def main():
     seq = args.seq or (128 if args.smoke else 4096)
     gbatch = args.global_batch or (8 if args.smoke else 256)
     print(f"arch={cfg.name} params={model.n_params()/1e6:.1f}M mesh={dict(mesh.shape)} "
+          f"devices={mesh.devices.flat[0].device_kind} x{mesh.size} "
           f"seq={seq} batch={gbatch}", flush=True)
 
     # ---- precision-policy resolution --------------------------------------

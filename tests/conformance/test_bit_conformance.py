@@ -4,8 +4,8 @@ Three mutually checking implementations must agree bit-for-bit on the
 entire float16 value space (all 65536 patterns — every normal, subnormal,
 ±0, ±inf and NaN payload, exactly widened to f32):
 
-  * ``quantize_ref_dynamic``   — the runtime-parameterized jnp path the
-                                 whole sweep/search stack runs on,
+  * ``quantize_dynamic(impl='ref')`` — the runtime-parameterized jnp path
+                                 the whole sweep/search stack runs on,
   * the Pallas kernel          — ``quantize_dynamic(impl='interpret')``,
   * ``bit_oracle``             — exact-integer RNE, no shared code,
 

@@ -55,9 +55,8 @@ def test_init_state_f64_starts_from_f32_bits(name):
     """The oracle contract: f64 initial data carries exactly the f32-rounded
     values, so trajectories differ by solver arithmetic only."""
     app = small_app(name)
-    from repro.compat import enable_x64
     s32 = app.init_state(jnp.float32)
-    with enable_x64():
+    with jax.enable_x64(True):
         s64 = app.init_state(jnp.float64)
         for a, b in zip(jax.tree_util.tree_leaves(s32),
                         jax.tree_util.tree_leaves(s64)):

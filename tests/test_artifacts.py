@@ -22,17 +22,9 @@ from repro.core import (
 )
 from repro.core.formats import E4M3, E4M3FN, FPFormat
 from repro.core.policy import magnitude_below, parse_policy
+from repro.launch.compilation import count_compiles
 from repro.models import Model
 from repro.serving.engine import Engine
-
-try:
-    from jax._src import test_util as _jtu
-    _count_compiles = _jtu.count_jit_compilation_cache_miss
-except (ImportError, AttributeError):  # jax moved the helper
-    _count_compiles = None
-
-needs_compile_counter = pytest.mark.skipif(
-    _count_compiles is None, reason="no jax compile-cache counter available")
 
 
 # --------------------------------------------------------------------------
@@ -391,7 +383,6 @@ def test_checkpoint_manifest_records_artifact(tmp_path):
     assert manifest["policy_artifact"] is None
 
 
-@needs_compile_counter
 def test_hotswap_train_step_zero_recompile(lm):
     """Deploying a different artifact mid-run is a new table VALUE, not a
     new executable: two different policies through one compiled step, with
@@ -417,7 +408,7 @@ def test_hotswap_train_step_zero_recompile(lm):
                                              params, batch)
     jit_step = jax.jit(step_fn)
     opt = init_opt_state(model, params, tc)
-    with _count_compiles() as n:
+    with count_compiles() as n:
         losses = {}
         for key, table in (("id", sites.identity_table()),
                            ("a", sites.table_for(pol_a)),

@@ -72,7 +72,7 @@ def test_elastic_reshard(tmp_path):
     """Save replicated, restore with explicit shardings on a 1-dev mesh
     (the same code path re-shards onto any elastic mesh shape)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("data",))
     ck = Checkpointer(str(tmp_path), async_save=False)
     t = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}

@@ -16,7 +16,7 @@ from repro.distributed import sharding as shd
 
 
 def mk_mesh(shape, names):
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     return make_mesh(shape, names)
 
 
@@ -124,7 +124,7 @@ SUBPROC = textwrap.dedent("""
     from repro.train.trainer import TrainConfig, make_train_step, init_opt_state
     from repro.optim.adamw import AdamWConfig
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("olmoe-1b-7b", "smoke").replace(dtype="float32")
     model = Model(cfg)

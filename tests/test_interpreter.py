@@ -133,9 +133,12 @@ def test_from_width_rule():
         return jnp.sum(x32 * 1.01)
     x = jnp.asarray(np.random.RandomState(6).randn(8), jnp.float32)
     pol = TruncationPolicy.from_flag("64_to_5_10")   # no f64 ops present
-    assert float(truncate(f, pol)(x)) == float(f(x))
+    # against the jitted plain function: eager op-by-op dispatch rounds the
+    # unfused multiply+sum differently from any compiled program
+    plain = float(jax.jit(f)(x))
+    assert float(truncate(f, pol)(x)) == plain
     pol32 = TruncationPolicy.from_flag("32_to_5_2")
-    assert float(truncate(f, pol32)(x)) != float(f(x))
+    assert float(truncate(f, pol32)(x)) != plain
 
 
 def test_dynamic_mask_truncation():
@@ -189,10 +192,10 @@ def test_scoped_policy_survives_grad():
         return jnp.sum(h ** 2)
     w = jnp.asarray(np.random.RandomState(0).randn(8, 8), jnp.float32)
     x = jnp.asarray(np.random.RandomState(1).randn(4, 8), jnp.float32)
-    g_full = jax.grad(loss)(w, x)
+    # jitted, like truncate's own output: eager dispatch rounds differently
+    g_full = jax.jit(jax.grad(loss))(w, x)
     g_tr = truncate(jax.grad(loss), TruncationPolicy.scoped("mlp", E5M2))(w, x)
     assert not np.allclose(np.asarray(g_full), np.asarray(g_tr))
     g_miss = truncate(jax.grad(loss),
                       TruncationPolicy.scoped("nothing", E5M2))(w, x)
-    np.testing.assert_allclose(np.asarray(g_full), np.asarray(g_miss),
-                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(g_full), np.asarray(g_miss))

@@ -70,8 +70,7 @@ def test_dtype_roundtrip(dtype):
 
 
 def test_f64_carrier():
-    from repro.compat import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         # genuine f64 values (not f32-exact upcasts)
         x64 = jnp.asarray(np.random.RandomState(0).randn(64).astype(np.float64)
                           / 3.0)

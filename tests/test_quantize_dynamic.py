@@ -80,8 +80,7 @@ def test_narrow_storage_dtypes(dtype):
 
 
 def test_f64_carrier_bit_for_bit():
-    from repro.compat import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         x64 = jnp.asarray(
             np.random.RandomState(0).randn(256).astype(np.float64) / 3.0)
         for fmt in (parse_format("5_14"), FPFormat(8, 30), FPFormat(11, 52),

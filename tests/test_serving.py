@@ -259,8 +259,11 @@ def test_drift_detection_pages_and_lands_in_provenance(lm):
 
 def test_no_drift_within_budget(lm):
     cfg, model, params = lm
+    # e8m16: its worst-element deviation on this model stays near 1e-2 for
+    # any weight draw; e8m10's ranges 0.27-0.58 across draws, straddling
+    # the 0.4 firing line, so it tested the draw and not the detector
     art = PolicyArtifact(name="stable",
-                         policy=TruncationPolicy.scoped("**/mlp", "e8m10"),
+                         policy=TruncationPolicy.scoped("**/mlp", "e8m16"),
                          provenance={"threshold": 1e-1})
     events = []
     eng = Engine(model, params, batch_size=2, max_seq_len=32, policy=art,

@@ -168,14 +168,14 @@ def test_sharded_ladder_single_compile_multi_probe_per_device():
     dispatches reuse ONE executable, and each dispatch evaluates more than
     one probe per device (K=8 on a 4-device probe mesh -> 2/device)."""
     out = _run_subproc(_PRELUDE + textwrap.dedent("""
-        from jax._src import test_util as _jtu
+        from repro.launch.compilation import count_compiles
         mesh = make_probe_mesh(4)
         site = TruncationPolicy.everywhere("e5m2")
         pols = [TruncationPolicy.everywhere(f"e8m{m}")
                 for m in (15, 10, 7, 5, 3, 2, 23, 11)]
         handle = truncate_sweep(_toy, site, mesh=mesh)(*args)
         tables = handle.tables(pols)
-        with _jtu.count_jit_compilation_cache_miss() as n:
+        with count_compiles() as n:
             a = jax.device_get(handle.batch(tables))
             b = jax.device_get(handle.batch(handle.tables(pols[::-1])))
         print("RESULT" + json.dumps({
@@ -296,7 +296,7 @@ def test_autosearch_mesh_matches_single_device_bench_model():
     out = _run_subproc(_PRELUDE + textwrap.dedent("""
         import sys, os
         sys.path.insert(0, os.getcwd())
-        from jax._src import test_util as _jtu
+        from repro.launch.compilation import count_compiles
         from benchmarks.common import bench_model, bench_batch
 
         cfg, model, params = bench_model()
@@ -306,7 +306,7 @@ def test_autosearch_mesh_matches_single_device_bench_model():
                                search.loss_degradation, 48,
                                threshold=5e-3, widths=widths)
         mesh = make_probe_mesh()   # all 8 devices
-        with _jtu.count_jit_compilation_cache_miss() as n:
+        with count_compiles() as n:
             r1 = search.autosearch(model.loss, (params, batch),
                                    search.loss_degradation, 48,
                                    threshold=5e-3, widths=widths, mesh=mesh)

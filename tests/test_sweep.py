@@ -15,15 +15,8 @@ from repro.core import (
 )
 from repro.core import policy as policy_mod
 from repro.core.policy import magnitude_below
+from repro.launch.compilation import count_compiles
 
-try:
-    from jax._src import test_util as _jtu
-    _count_compiles = _jtu.count_jit_compilation_cache_miss
-except (ImportError, AttributeError):  # jax moved the helper
-    _count_compiles = None
-
-needs_compile_counter = pytest.mark.skipif(
-    _count_compiles is None, reason="no jax compile-cache counter available")
 
 
 def _toy(w1, w2, x):
@@ -155,7 +148,6 @@ def test_site_policy_rejects_runtime_unrepresentable_rules():
         handle.table(masked)
 
 
-@needs_compile_counter
 def test_policy_ladder_single_compile():
     """The tentpole guarantee at executable level: N candidate policies
     through one sweep handle cost ONE XLA compilation (static `truncate`
@@ -163,22 +155,21 @@ def test_policy_ladder_single_compile():
     args = _toy_args()
     handle = truncate_sweep(_toy, TruncationPolicy.everywhere("e5m2"))(*args)
     tables = handle.tables(_POLICIES)
-    with _count_compiles() as n:
+    with count_compiles() as n:
         for i in range(len(_POLICIES)):
             handle(tables[i])
     assert n[0] == 1, f"per-candidate recompile detected ({n[0]} compiles)"
-    with _count_compiles() as n:
+    with count_compiles() as n:
         handle.batch(tables)
         handle.batch(handle.tables(_POLICIES[::-1]))  # same K -> same exe
     assert n[0] == 1, f"batched sweep recompiled ({n[0]} compiles)"
 
 
-@needs_compile_counter
 def test_autosearch_compile_budget_toy():
     """CI compile-count regression: the batched search must not recompile
     per candidate — one batched executable serves the whole run."""
     args = _toy_args()
-    with _count_compiles() as n:
+    with count_compiles() as n:
         res = search.autosearch(_toy, args, search.rel_error, 32,
                                 threshold=1e-2)
     assert res.converged
@@ -187,7 +178,6 @@ def test_autosearch_compile_budget_toy():
     assert res.n_compiles <= 2
 
 
-@needs_compile_counter
 @pytest.mark.slow
 def test_autosearch_compile_budget_bench_model():
     """Acceptance: autosearch on benchmarks.common.bench_model performs at
@@ -196,7 +186,7 @@ def test_autosearch_compile_budget_bench_model():
 
     cfg, model, params = bench_model()
     batch = bench_batch(cfg)
-    with _count_compiles() as n:
+    with count_compiles() as n:
         res = search.autosearch(model.loss, (params, batch),
                                 search.loss_degradation, 48, threshold=5e-3)
     assert n[0] <= 2, f"search compiled {n[0]} executables"
