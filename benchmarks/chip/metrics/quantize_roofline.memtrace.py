@@ -1,0 +1,7 @@
+"""Share of their bandwidth roofline that the quantize kernels reached in
+the traced steps."""
+from benchmarks.chip import readers
+
+
+def read(ctx):
+    return readers.kernel_roofline(ctx, "quantize_em")
