@@ -111,6 +111,8 @@ def _cached_transform(fn: Callable, build: Callable, fallback: Callable,
     ``fallback(closed, out_tree, leaves)`` -> direct (uncached) evaluation,
     used under an outer trace where caching a jaxpr would leak tracers.
     """
+    name = getattr(fn, "__qualname__", type(fn).__name__)
+
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         leaves, in_tree = jax.tree_util.tree_flatten((args, kwargs))
@@ -122,13 +124,18 @@ def _cached_transform(fn: Callable, build: Callable, fallback: Callable,
             if entry is not None:
                 return entry(leaves)
         wrapped.n_traces += 1
-        closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args, **kwargs)
-        out_tree = jax.tree_util.tree_structure(out_shape)
-        if not use_cache or _has_tracer(closed.consts):
-            return fallback(closed, out_tree, leaves)
-        entry = build(closed, out_tree, args, kwargs)
-        wrapped._cache[key] = entry
-        return entry(leaves)
+        # a miss traces, and its first call compiles: a span of its own in
+        # the profiler's trace, so that the time is not put down to its caller
+        with jax.profiler.TraceAnnotation("raptor/api.trace", fn=name,
+                                          n_traces=wrapped.n_traces):
+            closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(
+                *args, **kwargs)
+            out_tree = jax.tree_util.tree_structure(out_shape)
+            if not use_cache or _has_tracer(closed.consts):
+                return fallback(closed, out_tree, leaves)
+            entry = build(closed, out_tree, args, kwargs)
+            wrapped._cache[key] = entry
+            return entry(leaves)
 
     wrapped._cache = {}
     wrapped.n_traces = 0          # times the jaxpr walk actually ran

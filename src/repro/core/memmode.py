@@ -35,6 +35,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Sequence, Tuple
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -118,16 +120,20 @@ class RaptorReport:
             lax.psum(self.op_counts, axis_name))
 
     def merge(self, other: "RaptorReport") -> "RaptorReport":
-        """Host-side pairwise reduction (e.g. across processes/ranks)."""
+        """Host-side pairwise reduction (e.g. across processes/ranks, or the
+        ticks of a serving run). The result holds numpy arrays with the
+        counts in int64: a sum of many reports passes 2^31, where the
+        device's int32 would wrap."""
         if self.locations != other.locations:
             raise ValueError("RaptorReport.merge: location tables differ "
                              "(reports come from different computations)")
         return RaptorReport(
             self.locations,
-            jnp.asarray(self.flags) + jnp.asarray(other.flags),
-            jnp.maximum(jnp.asarray(self.max_rel),
-                        jnp.asarray(other.max_rel)),
-            jnp.asarray(self.op_counts) + jnp.asarray(other.op_counts))
+            np.asarray(self.flags, np.int64)
+            + np.asarray(other.flags, np.int64),
+            np.maximum(np.asarray(self.max_rel), np.asarray(other.max_rel)),
+            np.asarray(self.op_counts, np.int64)
+            + np.asarray(other.op_counts, np.int64))
 
     @staticmethod
     def merge_all(reports: Sequence["RaptorReport"]) -> "RaptorReport":

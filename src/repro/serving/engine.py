@@ -173,11 +173,13 @@ class Engine:
             out[key] = jax.tree_util.tree_map(zero_lane(axis), sub)
         return out
 
-    def _admit(self):
+    def _admit(self) -> int:
         """Admit queued requests into free slots — continuously: any free
         (including just-quarantined) slot takes the next request while the
         other slots keep decoding. The slot's cache lanes are zeroed so the
-        new request starts from a fresh cursor."""
+        new request starts from a fresh cursor. Returns how many were
+        admitted."""
+        admitted = 0
         free = [s for s in range(self.B) if self.slots[s] is None]
         for s in free:
             if not self._queue:
@@ -187,6 +189,8 @@ class Engine:
             self.slots[s] = req
             self.lengths[s] = 0
             req._fed = 0
+            admitted += 1
+        return admitted
 
     def _finish(self, slot: int, req: Request):
         req.done = True
@@ -201,67 +205,109 @@ class Engine:
         live slot — prompt tokens for slots still prefilling, the previous
         output token for decoding slots — through a single batched decode
         call. A slot emits its next output token on the tick that feeds its
-        final prompt token (masked prefill and decode interleave freely)."""
-        self._admit()
-        live = [s for s in range(self.B) if self.slots[s] is not None]
-        if not live:
-            return False
-        tok = np.zeros((self.B,), np.int32)
-        emitting = []
-        for s in live:
-            req = self.slots[s]
-            if req._fed < len(req.prompt):
-                tok[s] = req.prompt[req._fed]
-                if req._fed == len(req.prompt) - 1:
-                    emitting.append(s)
-            else:
-                tok[s] = req.out_tokens[-1]
-                emitting.append(s)
+        final prompt token (masked prefill and decode interleave freely).
 
-        shadow_live = [s for s in live if self.slots[s].shadowed]
-        if self._shadow is not None and shadow_live:
-            logits, self.cache, report = self._shadow.step(
-                self.params, self.cache, jnp.asarray(tok))
-            self._shadow.observe(report,
-                                 [self.slots[s] for s in shadow_live],
-                                 self._tick)
-            event = self._shadow.check(self._tick)
-            if event is not None and self.artifact is not None:
-                self.artifact = self._shadow.log.attach(self.artifact)
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(tok))
-        self.assert_zero_recompile()
+        Each phase is a ``raptor/engine.*`` span in the profiler's trace
+        (the README lists them), under one ``raptor/engine.step`` span per
+        tick; ``raptor/engine.wait`` is where the host first blocks on the
+        tick's device work."""
+        with jax.profiler.StepTraceAnnotation("raptor/engine.step",
+                                              step_num=self._tick):
+            with jax.profiler.TraceAnnotation("raptor/engine.admit") as span:
+                span.set_metadata(admitted=self._admit(),
+                                  queued=len(self._queue))
+            live = [s for s in range(self.B) if self.slots[s] is not None]
+            if not live:
+                return False
+            with jax.profiler.TraceAnnotation("raptor/engine.feed"):
+                tok = np.zeros((self.B,), np.int32)
+                emitting = []
+                for s in live:
+                    req = self.slots[s]
+                    if req._fed < len(req.prompt):
+                        tok[s] = req.prompt[req._fed]
+                        if req._fed == len(req.prompt) - 1:
+                            emitting.append(s)
+                    else:
+                        tok[s] = req.out_tokens[-1]
+                        emitting.append(s)
+                tok = jnp.asarray(tok)
 
-        for s in live:
-            req = self.slots[s]
-            if req._fed < len(req.prompt):
-                req._fed += 1
-            self.lengths[s] += 1
+            shadow_live = [s for s in live if self.slots[s].shadowed]
+            shadowed = self._shadow is not None and bool(shadow_live)
+            report = None
+            with jax.profiler.TraceAnnotation(
+                    "raptor/engine.dispatch",
+                    path="shadow" if shadowed else "decode", live=len(live),
+                    emitting=len(emitting)):
+                if shadowed:
+                    logits, self.cache, report = self._shadow.step(
+                        self.params, self.cache, tok)
+                else:
+                    logits, self.cache = self._decode(self.params,
+                                                      self.cache, tok)
+                self.assert_zero_recompile()
 
-        logits_np = np.asarray(logits)
-        nxt = np.argmax(logits_np, axis=-1)
-        # quarantine non-finite decode: a slot whose logits went NaN/Inf
-        # (numerically broken policy, corrupted params) fails THAT request
-        # with a clear status and frees the slot for the next admission — an
-        # argmax over NaN logits would otherwise silently emit token 0 and
-        # poison the stream
-        finite = np.isfinite(logits_np).all(axis=-1)
-        for s in emitting:
-            req = self.slots[s]
-            if not finite[s]:
-                req.status = "error_nonfinite"
-                req.error = (f"non-finite logits while decoding token "
-                             f"{len(req.out_tokens) + 1} (slot {s}); "
-                             "request quarantined")
-                self._finish(s, req)
-                continue
-            req.out_tokens.append(int(nxt[s]))
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or self.lengths[s] >= self.S - 1):
-                self._finish(s, req)
-        self._tick += 1
-        return True
+            for s in live:
+                req = self.slots[s]
+                if req._fed < len(req.prompt):
+                    req._fed += 1
+                self.lengths[s] += 1
+
+            # the tick's outputs go to the host in one round trip: the
+            # logits, and on a shadowed tick the report, which observe merges
+            # on the host. The copies are asked for before the host blocks,
+            # so that the runtime starts them as soon as the step is done.
+            outs = jax.tree_util.tree_leaves((logits, report))
+            with jax.profiler.TraceAnnotation("raptor/engine.wait"):
+                for x in outs:
+                    x.copy_to_host_async()
+                jax.block_until_ready(outs)
+            with jax.profiler.TraceAnnotation(
+                    "raptor/engine.fetch",
+                    bytes=sum(x.nbytes for x in outs)):
+                logits_np, report = jax.device_get((logits, report))
+            if shadowed:
+                with jax.profiler.TraceAnnotation("raptor/shadow.observe",
+                                                  requests=len(shadow_live)):
+                    self._shadow.observe(report,
+                                         [self.slots[s] for s in shadow_live],
+                                         self._tick)
+                with jax.profiler.TraceAnnotation("raptor/shadow.check"):
+                    event = self._shadow.check(self._tick)
+                if event is not None and self.artifact is not None:
+                    self.artifact = self._shadow.log.attach(self.artifact)
+
+            with jax.profiler.TraceAnnotation("raptor/engine.sample"):
+                nxt = np.argmax(logits_np, axis=-1)
+                # quarantine non-finite decode: a slot whose logits went
+                # NaN/Inf (numerically broken policy, corrupted params)
+                # fails THAT request with a clear status and frees the slot
+                # for the next admission — an argmax over NaN logits would
+                # otherwise silently emit token 0 and poison the stream
+                finite = np.isfinite(logits_np).all(axis=-1)
+            with jax.profiler.TraceAnnotation("raptor/engine.emit") as span:
+                emitted = finished = quarantined = 0
+                for s in emitting:
+                    req = self.slots[s]
+                    if not finite[s]:
+                        req.status = "error_nonfinite"
+                        req.error = (f"non-finite logits while decoding "
+                                     f"token {len(req.out_tokens) + 1} "
+                                     f"(slot {s}); request quarantined")
+                        self._finish(s, req)
+                        quarantined += 1
+                        continue
+                    req.out_tokens.append(int(nxt[s]))
+                    emitted += 1
+                    if (len(req.out_tokens) >= req.max_new_tokens
+                            or self.lengths[s] >= self.S - 1):
+                        self._finish(s, req)
+                        finished += 1
+                span.set_metadata(emitted=emitted, finished=finished,
+                                  quarantined=quarantined)
+            self._tick += 1
+            return True
 
     def run(self) -> Dict[int, Request]:
         while self._queue or any(s is not None for s in self.slots):

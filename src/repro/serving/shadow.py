@@ -72,8 +72,8 @@ class DriftEvent:
 class ShadowProfiler:
     """Owns the shadowed decode step, the sampling RNG, the rolling report,
     and the drift detector. The engine calls :meth:`sample` at submit,
-    :meth:`step` on ticks with live shadowed slots, and :meth:`check` after
-    every shadowed tick."""
+    :meth:`step` on ticks with live shadowed slots, and :meth:`observe` and
+    :meth:`check` once that step's outputs are on the host."""
 
     def __init__(self, step_fn, policy, config: ShadowConfig, artifact=None):
         if policy is None:
@@ -121,7 +121,10 @@ class ShadowProfiler:
     def observe(self, report, shadow_requests: Sequence, tick: int) -> None:
         """Merge one tick's report into the rolling serving report and into
         each live shadowed request's per-request report (exact reductions:
-        SUM for flags/op_counts, MAX for max_rel)."""
+        SUM for flags/op_counts, MAX for max_rel). ``RaptorReport.merge``
+        runs on the host with int64 counts, so that a rolling count does
+        not wrap at 2^31; the engine hands over a report fetched with the
+        tick's logits."""
         rep = getattr(report, "totals", report)   # TrajectoryReport -> totals
         self.report = rep if self.report is None else self.report.merge(rep)
         for req in shadow_requests:

@@ -89,7 +89,7 @@ def test_merge_empty_sentinel_reports():
 
 def test_merge_numpy_inputs_promote():
     """Host-side merging accepts numpy-stat reports (e.g. deserialized from
-    another process) thanks to the jnp.asarray coercion in merge."""
+    another process) beside device ones: merge reads both as numpy."""
     a = RaptorReport(("l0",), np.asarray([2]), np.asarray([0.5], np.float32),
                      np.asarray([7]))
     b = _report(["l0"], [3], [0.125], [5])
@@ -97,3 +97,17 @@ def test_merge_numpy_inputs_promote():
     assert _get(m.flags).tolist() == [5]
     assert _get(m.max_rel).tolist() == [0.5]
     assert _get(m.op_counts).tolist() == [12]
+
+
+def test_merge_counts_past_int32():
+    """Counts merge in int64 on the host: three device reports of 2^31 - 1
+    elements a location sum past 2^31, where the device's int32 would
+    wrap; max_rel stays a float max."""
+    big = [2**31 - 1, 2**30]
+    a = _report(["l0", "l1"], big, [0.5, 0.25], big)
+    m = RaptorReport.merge_all([a, a, a])
+    for counts in (m.flags, m.op_counts):
+        assert counts.dtype == np.int64
+        assert counts.tolist() == [3 * (2**31 - 1), 3 * 2**30]
+    assert m.max_rel.dtype == np.float32
+    assert m.max_rel.tolist() == [0.5, 0.25]
