@@ -136,12 +136,18 @@ def _resolve(mesh: Mesh, rules: dict, logical: Tuple[Optional[str], ...],
     return P(*spec)
 
 
+def mesh_active() -> bool:
+    """Whether ``use_mesh`` holds a mesh of more than one device."""
+    mesh = _ctx().mesh
+    return mesh is not None and mesh.size > 1
+
+
 def constrain(x, *logical: Optional[str]):
     """Apply a logical-axis sharding constraint to an activation. No-op
     without an active mesh (single-device smoke tests)."""
-    ctx = _ctx()
-    if ctx.mesh is None or ctx.mesh.size == 1:
+    if not mesh_active():
         return x
+    ctx = _ctx()
     if len(logical) != x.ndim:
         raise ValueError(f"rank mismatch: {logical} vs {x.shape}")
     spec = _resolve(ctx.mesh, ctx.act_rules, logical, x.shape)

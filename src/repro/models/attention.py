@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, mesh_active
+from repro.kernels import kv_write
 from repro.models import common
 from repro.models.common import ParamDef
 
@@ -116,17 +117,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: Optional[int] = None,
-                     scale: Optional[float] = None, ring: bool = False):
+                     scale: Optional[float] = None):
     """One-token attention. q: (B, Hq, Dk); caches: (B, Hkv, S, D*);
-    pos: int32 scalar or (B,) vector — per-slot count of valid cache entries
-    (the new token's index in slot b is pos[b]-1 after the cache update).
-    A scalar means every batch lane sits at the same cursor; the serving
-    engine passes a ragged (B,) vector so slots decode independently.
-
-    ``ring=True``: the cache is a ring buffer of size S == window; slot s
-    holds the token at position pos - ((pos - s) mod S) — negative means the
-    slot hasn't been written yet (masked). No separate window mask needed:
-    the ring IS the window."""
+    pos: int32 scalar or (B,) vector — per-slot count of valid cache
+    entries. A scalar means every batch lane sits at the same cursor."""
     B, Hq, Dk = q.shape
     Hkv, S = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hkv
@@ -138,17 +132,63 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: Optional[int] = None,
                    k_cache.astype(jnp.float32)) * scale
     idx = jnp.arange(S)[None, None, None, :]
     cur = pos_b[:, None, None, None]
-    if ring:
-        last = cur - 1  # index of the newest token (already inserted)
-        slot_pos = last - jnp.mod(last - idx, S)
-        valid = slot_pos >= 0
-    else:
-        valid = idx < cur
-        if window is not None:
-            valid &= idx >= (cur - window)
+    valid = idx < cur
+    if window is not None:
+        valid &= idx >= (cur - window)
     s = jnp.where(valid, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32))
+    return out.reshape(B, Hq, -1).astype(v_cache.dtype)
+
+
+def decode_attention_with_new(q, k_cache, v_cache, k_new, v_new, pos, *,
+                              window: Optional[int] = None,
+                              ring: bool = False):
+    """One-token attention over the cache as it stands BEFORE the new
+    token's write, plus the new token itself: the same entries, and the
+    same result up to summation order, as ``decode_attention`` over the
+    written cache, without writing it.
+
+    q: (B, Hq, Dk); caches: (B, Hkv, S, D*); k_new/v_new: (B, Hkv, D*),
+    already in the cache dtype. pos: (B,) count of tokens in each slot
+    before this one (the new token's position).
+
+    Non-ring: old entries ``idx < pos`` (and inside the window) count; a
+    cursor past the cache end would write nothing, so its new token does
+    not count either. ``ring=True`` (S == window): slot ``pos mod S`` holds
+    the token about to fall out of the window, so it is masked; the other
+    slots count once written (slot s holds position pos - ((pos - s) mod
+    S), negative while unwritten)."""
+    B, Hq, Dk = q.shape
+    Hkv, S = k_cache.shape[1], k_cache.shape[2]
+    G = Hq // Hkv
+    f32 = jnp.float32
+    scale = 1.0 / math.sqrt(Dk)
+
+    qg = q.reshape(B, Hkv, G, Dk).astype(f32)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k_cache.astype(f32)) * scale
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new.astype(f32)) * scale
+    idx = jnp.arange(S)[None, None, None, :]
+    cur = pos[:, None, None, None]
+    if ring:
+        age = jnp.mod(cur - idx, S)          # 0: the slot being overwritten
+        valid = (age > 0) & (cur - age >= 0)
+        new_valid = jnp.ones((B,), bool)
+    else:
+        valid = idx < cur
+        if window is not None:
+            valid &= idx > cur - window
+        new_valid = pos < S
+    new_valid = new_valid[:, None, None]
+    s = jnp.where(valid, s, NEG_INF)
+    s_new = jnp.where(new_valid, s_new, NEG_INF)
+    m = jnp.maximum(jnp.max(s, axis=-1), s_new)
+    p = jnp.exp(s - m[..., None])
+    p_new = jnp.where(new_valid, jnp.exp(s_new - m), 0.0)
+    denom = jnp.sum(p, axis=-1) + p_new
+    out = (jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(f32))
+           + p_new[..., None] * v_new.astype(f32)[:, :, None])
+    out = out / denom[..., None]
     return out.reshape(B, Hq, -1).astype(v_cache.dtype)
 
 
@@ -220,33 +260,102 @@ def gqa_forward(p, x, cfg: ArchConfig, *, positions, causal: bool = True,
     return out, (k, v)
 
 
-def gqa_decode(p, x1, cache, pos, cfg: ArchConfig, *,
-               window: Optional[int] = None, positions3=None):
-    """x1: (B, 1, d); cache: dict(k=(B,Hkv,S,hd), v=...). pos: scalar or
-    (B,) count of tokens already in each slot's cache (ragged decode writes
-    each lane at its own cursor). When the cache was allocated ring-sized
-    (S == window < requested seq_len) the slot is pos mod S; a non-ring
-    cursor past the cache end simply doesn't write (dead serving lanes)."""
-    B = x1.shape[0]
-    hd = cfg.resolved_head_dim
-    S_cache = cache["k"].shape[2]
+def _cache_slot(pos_b, S_cache: int, window: Optional[int]):
+    """(slot each lane writes, whether the cache is a ring): a ring cache
+    (S == window) writes at pos mod S, any other at pos itself."""
     ring = window is not None and S_cache == window
+    return (jnp.mod(pos_b, S_cache) if ring else pos_b), ring
+
+
+def gqa_decode_rows(p, x1, cache, pos, cfg: ArchConfig, *,
+                    window: Optional[int] = None, positions3=None):
+    """Decode attention that reads the layer's cache without writing it.
+    x1: (B, 1, d); cache: dict(k=(B,Hkv,S,hd), v=...); pos: scalar or (B,)
+    count of tokens already in each slot's cache. Returns (out (B, 1, d),
+    rows): rows is dict(k=, v=) of the new token's (B, Hkv, hd), in the
+    cache dtype, for :func:`gqa_write` (or a stacked write) to store."""
+    B = x1.shape[0]
+    S_cache = cache["k"].shape[2]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     if cfg.rope_type == "mrope" and positions3 is None:
         positions3 = jnp.broadcast_to(pos_b[None, :, None], (3, B, 1))
     positions = pos_b[:, None]
     q, k, v = _project_qkv(
         p, x1, cfg, positions3 if cfg.rope_type == "mrope" else positions)
-    slot = jnp.mod(pos_b, S_cache) if ring else pos_b
-    onehot = jnp.arange(S_cache)[None, :] == slot[:, None]   # (B, S)
-    k_cache = jnp.where(onehot[:, None, :, None],
-                        k.astype(cache["k"].dtype), cache["k"])
-    v_cache = jnp.where(onehot[:, None, :, None],
-                        v.astype(cache["v"].dtype), cache["v"])
-    o = decode_attention(q[:, :, 0], k_cache, v_cache, pos_b + 1,
-                         window=None if ring else window, ring=ring)
+    k_new = k[:, :, 0].astype(cache["k"].dtype)
+    v_new = v[:, :, 0].astype(cache["v"].dtype)
+    _, ring = _cache_slot(pos_b, S_cache, window)
+    o = decode_attention_with_new(q[:, :, 0], cache["k"], cache["v"],
+                                  k_new, v_new, pos_b,
+                                  window=None if ring else window, ring=ring)
     out = o.reshape(B, 1, -1) @ p["wo"].astype(x1.dtype)
-    return out, {"k": k_cache, "v": v_cache}
+    return out, {"k": k_new, "v": v_new}
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def gqa_write(cache, rows, pos, *, window: Optional[int] = None):
+    """Write each lane's new K/V row into the cache at its slot: one layer's
+    cache (B,Hkv,S,hd) with rows (B,Hkv,hd), or the stacked (L,B,Hkv,S,hd)
+    cache with every layer's rows (L,B,Hkv,hd). A non-ring cursor past the
+    cache end writes nothing (dead serving lanes).
+
+    In place when the cache is donated. On TPU (S a multiple of 128) the
+    ``kv_write`` kernel rewrites the 128-slot block that holds each row;
+    elsewhere one ``dynamic_update_slice`` a lane. A scatter, or a write
+    that reads its slot back, has XLA on TPU copy the whole cache into
+    another layout and back around it. Under a mesh the cache is sharded
+    (slots, and the sequence for context parallelism), where an update at
+    a dynamic sequence index gathers the cache: there a select over it
+    stays local to each shard."""
+    k = cache["k"]
+    ax = k.ndim - 4                      # the batch axis
+    B, S_cache = k.shape[ax], k.shape[-2]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    slot, ring = _cache_slot(pos_b, S_cache, window)
+    if mesh_active():
+        hit = (jnp.arange(S_cache) == slot[:, None])[:, None, :, None]
+        return {n: jnp.where(hit, jnp.expand_dims(rows[n], -2), cache[n])
+                for n in ("k", "v")}
+    if _on_tpu() and S_cache % kv_write.LANES == 0:
+        # the kernel takes a stacked cache, sequence last: a bitcast on TPU
+        def stack(t):
+            return t if ax else t[None]
+
+        out = kv_write.kv_write(
+            *(jnp.swapaxes(stack(cache[n]), -1, -2) for n in ("k", "v")),
+            *(stack(rows[n]) for n in ("k", "v")), slot)
+        return {n: jnp.swapaxes(o, -1, -2).reshape(cache[n].shape)
+                for n, o in zip(("k", "v"), out)}
+    out = {}
+    for n in ("k", "v"):
+        c, r = cache[n], rows[n]
+        if not ring:
+            # the write clamps a cursor past the end to the last entry:
+            # write back what that entry holds
+            dead = (pos_b >= S_cache)[:, None, None]
+            r = jnp.where(dead, c[..., S_cache - 1, :], r)
+        for b in range(B):
+            upd = jnp.expand_dims(lax.index_in_dim(r, b, ax), -2)
+            c = lax.dynamic_update_slice(c, upd,
+                                         (0,) * ax + (b, 0, slot[b], 0))
+        out[n] = c
+    return out
+
+
+def gqa_decode(p, x1, cache, pos, cfg: ArchConfig, *,
+               window: Optional[int] = None, positions3=None):
+    """x1: (B, 1, d); cache: dict(k=(B,Hkv,S,hd), v=...). pos: scalar or
+    (B,) count of tokens already in each slot's cache (ragged decode writes
+    each lane at its own cursor). When the cache was allocated ring-sized
+    (S == window < requested seq_len) the slot is pos mod S; a non-ring
+    cursor past the cache end simply doesn't write (dead serving lanes).
+    Returns (out, updated layer cache)."""
+    out, rows = gqa_decode_rows(p, x1, cache, pos, cfg, window=window,
+                                positions3=positions3)
+    return out, gqa_write(cache, rows, pos, window=window)
 
 
 def gqa_init_cache(cfg: ArchConfig, batch: int, seq_len: int, dtype,

@@ -106,16 +106,20 @@ def layer_param_defs(cfg: ArchConfig, kind: str) -> dict:
 
 
 def _seq_mix(cfg: ArchConfig, p, x, positions, is_global, mix_state,
-             decode: bool, pos):
+             decode: bool, pos, rows_only: bool = False):
     """Dispatch the sequence-mixing block. Returns (y, new_mix_state).
     ``is_global=True`` lifts the sliding window (global-attention layers are
     executed as their own unrolled segments so the window stays static and
-    the flash path can skip out-of-window KV blocks)."""
+    the flash path can skip out-of-window KV blocks). ``rows_only`` (GQA
+    decode): leave the cache unwritten and return the new token's K/V rows
+    in place of the new state, for one stacked write after the scan."""
     if cfg.attn_type == "gqa":
         window = None if is_global else cfg.sliding_window
         if decode:
-            return attention.gqa_decode(p["attn"], x, mix_state, pos, cfg,
-                                        window=window)
+            decode_fn = (attention.gqa_decode_rows if rows_only
+                         else attention.gqa_decode)
+            return decode_fn(p["attn"], x, mix_state, pos, cfg,
+                             window=window)
         with jax.named_scope("attn"):
             y, _ = attention.gqa_forward(p["attn"], x, cfg, positions=positions,
                                          window=window)
@@ -167,12 +171,13 @@ def _seq_mix(cfg: ArchConfig, p, x, positions, is_global, mix_state,
 
 def layer_forward(cfg: ArchConfig, p, x, positions, kind: str,
                   is_global=None, mix_state=None, decode: bool = False,
-                  pos=None):
-    """One decoder layer. Returns (x, new_mix_state)."""
+                  pos=None, rows_only: bool = False):
+    """One decoder layer. Returns (x, new_mix_state); with ``rows_only``
+    (GQA decode) the new K/V rows take the new state's place."""
     with jax.named_scope("pre_norm"):
         h = apply_norm(p["norm1"], x, cfg)
     y, new_state = _seq_mix(cfg, p, h, positions, is_global, mix_state,
-                            decode, pos)
+                            decode, pos, rows_only)
     x = x + y
     with jax.named_scope("post_norm"):
         h = apply_norm(p["norm2"], x, cfg)
@@ -439,18 +444,23 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, embeds=None):
 
     n_stack = cfg.n_layers - _n_lead(cfg)
 
-    def make_body(is_global):
+    def make_body(is_global, rows_only=False):
         def body(x, xs):
             p_l, cache_l = xs
             with jax.named_scope("layer"):
                 x, st = layer_forward(cfg, p_l, x, positions,
                                       _stack_kind(cfg), is_global=is_global,
-                                      mix_state=cache_l, decode=True, pos=pos)
+                                      mix_state=cache_l, decode=True, pos=pos,
+                                      rows_only=rows_only)
             return x, st
         return body
 
     if cfg.scan_layers:
-        scan_caches = []
+        # a GQA stack reads its cache inside the scan and writes every
+        # layer's new rows after it, in place when the cache is donated,
+        # instead of a rewrite of each layer's whole cache
+        rows_only = cfg.attn_type == "gqa"
+        scan_outs = []
         new_globals = []
         c_off = 0          # cursor into the compacted ring-cache stack
         for kind, lo, hi in segments(cfg):
@@ -458,17 +468,22 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, embeds=None):
             if kind == "scan":
                 n_seg = hi - lo
                 c_seg = _tree_slice(cache["layers"], c_off, c_off + n_seg)
-                x, st = lax.scan(make_body(False), x, (p_seg, c_seg))
-                scan_caches.append(st)
+                x, st = lax.scan(make_body(False, rows_only), x,
+                                 (p_seg, c_seg))
+                scan_outs.append(st)
                 c_off += n_seg
             else:
                 c_l = cache["global"][len(new_globals)]
                 x, st1 = make_body(True)(x, (_tree_index(params["layers"], lo),
                                              c_l))
                 new_globals.append(st1)
+        # the scan segments cover the compacted stack in order
         new_stack = (jax.tree_util.tree_map(
-            lambda *ts: jnp.concatenate(ts, axis=0), *scan_caches)
-            if len(scan_caches) > 1 else scan_caches[0])
+            lambda *ts: jnp.concatenate(ts, axis=0), *scan_outs)
+            if len(scan_outs) > 1 else scan_outs[0])
+        if rows_only:
+            new_stack = attention.gqa_write(cache["layers"], new_stack, pos,
+                                            window=cfg.sliding_window)
         if new_globals:
             new_cache["global"] = new_globals
     else:

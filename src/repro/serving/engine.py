@@ -102,8 +102,12 @@ class Engine:
         self.cache = jax.tree_util.tree_map(
             lambda t: jax.device_put(jnp.zeros(t.shape, t.dtype),
                                      t.sharding), warmed)
-        self._decode = jax.jit(lambda p, c, t, _fn=step: _fn(p, c, t))
-        self._reset = jax.jit(lambda c, s, _fn=self._slot_reset: _fn(c, s))
+        # the cache is donated to the decode step and to the slot reset, so
+        # that each writes its rows in place instead of copying the cache
+        self._decode = jax.jit(lambda p, c, t, _fn=step: _fn(p, c, t),
+                               donate_argnums=(1,))
+        self._reset = jax.jit(lambda c, s, _fn=self._slot_reset: _fn(c, s),
+                              donate_argnums=(0,))
         self._shadow: Optional[ShadowProfiler] = None
         if shadow is not None:
             self._shadow = ShadowProfiler(raw_step, self.policy, shadow,
@@ -161,11 +165,14 @@ class Engine:
         """Zero exactly one batch lane of every cache leaf (jit'd once; the
         slot index is a traced scalar so admission never retraces). Stacked
         ``layers`` / encdec cross leaves carry batch at axis 1, everything
-        else (pos, lead, global, recurrent states) at axis 0."""
+        else (pos, lead, global, recurrent states) at axis 0. The lane is
+        zeroed, not only its cursor reset: a quarantined request can leave
+        NaN there, which ``p @ v`` would carry even under a mask."""
         def zero_lane(axis):
             def fn(t):
-                lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, axis)
-                return jnp.where(lane == slot, jnp.zeros_like(t), t)
+                shape = t.shape[:axis] + (1,) + t.shape[axis + 1:]
+                return jax.lax.dynamic_update_slice_in_dim(
+                    t, jnp.zeros(shape, t.dtype), slot, axis)
             return fn
         out = {}
         for key, sub in cache.items():
@@ -236,10 +243,12 @@ class Engine:
             shadow_live = [s for s in live if self.slots[s].shadowed]
             shadowed = self._shadow is not None and bool(shadow_live)
             report = None
+            # in_place: the step took the cache donated and writes its new
+            # rows in place; the decode and the shadowed step both do
             with jax.profiler.TraceAnnotation(
                     "raptor/engine.dispatch",
                     path="shadow" if shadowed else "decode", live=len(live),
-                    emitting=len(emitting)):
+                    emitting=len(emitting), in_place=1):
                 if shadowed:
                     logits, self.cache, report = self._shadow.step(
                         self.params, self.cache, tok)

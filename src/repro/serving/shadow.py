@@ -19,6 +19,8 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
+
 from repro.core.api import memtrace, profile_trajectory
 from repro.guardrails.log import GuardrailLog
 
@@ -82,15 +84,19 @@ class ShadowProfiler:
                 "truncation policy; construct the Engine with policy=... "
                 "(or an artifact) to enable it")
         if config.mode == "trajectory":
-            self._step = profile_trajectory(step_fn, policy,
-                                            threshold=config.threshold,
-                                            n_steps=config.n_steps)
+            shadowed = profile_trajectory(step_fn, policy,
+                                          threshold=config.threshold,
+                                          n_steps=config.n_steps)
         elif config.mode == "memtrace":
-            self._step = memtrace(step_fn, policy,
-                                  threshold=config.threshold)
+            shadowed = memtrace(step_fn, policy, threshold=config.threshold)
         else:
             raise ValueError(f"unknown shadow mode {config.mode!r}; "
                              "expected 'memtrace' or 'trajectory'")
+        # the cache is donated, as to the engine's decode step, so that the
+        # step writes its new rows in place: undonated, it would copy the
+        # whole cache on every shadowed tick
+        self._step = jax.jit(lambda p, c, t, _fn=shadowed: _fn(p, c, t),
+                             donate_argnums=(1,))
         self.config = config
         self.artifact = artifact
         self._rng = np.random.RandomState(config.seed)
@@ -114,7 +120,8 @@ class ShadowProfiler:
     def step(self, params, cache, tokens):
         """Paired truncated/shadow execution of one decode tick. Returns
         ``(logits, new_cache, report)`` — logits/cache are the truncated
-        lane, bit-identical to the plain deployed step."""
+        lane, bit-identical to the plain deployed step. ``cache`` is
+        donated: use the returned one."""
         (logits, new_cache), report = self._step(params, cache, tokens)
         return logits, new_cache, report
 
@@ -166,9 +173,8 @@ class ShadowProfiler:
             hook(event)
         return event
 
-    def cache_size(self) -> Optional[int]:
-        fn = getattr(self._step, "cache_size", None)
-        return None if fn is None else int(fn())
+    def cache_size(self) -> int:
+        return int(self._step._cache_size())
 
 
 __all__ = ["ShadowConfig", "ShadowProfiler", "DriftEvent"]

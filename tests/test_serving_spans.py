@@ -96,6 +96,18 @@ def test_shadow_spans_exactly_on_shadowed_ticks(traced):
     assert paths.count("shadow") == eng._shadow.shadow_ticks
 
 
+def test_in_place_on_every_tick(traced):
+    """``in_place`` is 1 on every tick, decode and shadowed alike: both
+    steps take the cache donated and write their new rows in place."""
+    _, _, tr = traced
+    flags = {"decode": set(), "shadow": set()}
+    for _, children in tr.trees("raptor/engine.step"):
+        (dispatch,) = [c for c in children
+                       if c[2] == "raptor/engine.dispatch"]
+        flags[dispatch[3]["path"]].add(dispatch[3]["in_place"])
+    assert flags == {"decode": {1}, "shadow": {1}}
+
+
 def test_emitted_counts_the_tokens_served(traced):
     _, reqs, tr = traced
     counts = {"emitted": 0, "finished": 0, "quarantined": 0}

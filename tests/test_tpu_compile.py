@@ -84,3 +84,54 @@ def test_wkv6_epilogue_rwkv6_7b(spec):
         a, a, a, a, spec((64, 64), jnp.float32),
         spec((1, 64, 64, 64), jnp.float32), spec((4,), jnp.int32))
     assert KERNEL in text
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kv_write", "update_slices"])
+def test_danube_decode_writes_kv_cache_in_place(spec, monkeypatch, kernel):
+    """h2o-danube-1.8b's decode step at serving width (four of its layers,
+    a cache too large for on-chip memory; 16 slots of 2048): with the
+    cache donated, as the engine donates it, the compiled step hands the
+    cache back in its own buffers, and no instruction copies, relayouts,
+    selects over or scatters into the stacked cache. Its writer is the
+    ``kv_write`` kernel on TPU (asked for here: this host's backend is the
+    CPU), else one update slice a slot."""
+    import re
+
+    import numpy as np
+
+    from repro.configs.base import get_config
+    from repro.core import TruncationPolicy
+    from repro.core.api import truncate
+    from repro.models import Model, attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: kernel)
+    cfg = get_config("h2o-danube-1.8b").replace(n_layers=4)
+    model = Model(cfg)
+    B, S = 16, 2048
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(lambda t: spec(t.shape, t.dtype), tree)
+
+    params = shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(lambda: model.init_cache(B, S)))
+    step = truncate(model.decode_step,
+                    TruncationPolicy.scoped("**/mlp", "e5m7"), impl="pallas")
+    compiled = jax.jit(lambda p, c, t: step(p, c, t),
+                       donate_argnums=(1,)).lower(
+        params, cache, spec((B,), jnp.int32)).compile()
+    cache_bytes = sum(int(np.prod(t.shape)) * t.dtype.itemsize
+                      for t in jax.tree_util.tree_leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    ops = set()
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = bf16\[4,16,8,(2048,80|80,2048)\]"
+                     r"\S* ([\w-]+)\(", line)
+        if m:
+            ops.add(m.group(2))
+    if kernel:
+        # the kernel's outputs, as bitcasts of the cache's own buffers
+        assert ops == {"parameter", "bitcast", "get-tuple-element"}, ops
+    else:
+        assert ops == {"parameter", "get-tuple-element",
+                       "dynamic-update-slice"}, ops
