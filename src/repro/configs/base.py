@@ -8,23 +8,41 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                # the router's outputs: every expert
     top_k: int
     d_expert: int
     n_shared: int = 0
     first_k_dense: int = 0
     d_ff_dense: int = 0           # d_ff of the leading dense layers
-    renormalize: bool = True
-    capacity_factor: float = 1.25
+    renormalize: bool = True      # norm_topk_prob
+    routed_scaling_factor: float = 1.0
+    # the expert ids this chip holds (expert parallelism); None: all of them
+    held_experts: Optional[Tuple[int, ...]] = None
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        return (tuple(range(self.n_experts)) if self.held_experts is None
+                else tuple(self.held_experts))
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int
+    q_lora: Optional[int]         # None: q is one plain projection
     kv_lora: int
     rope_head_dim: int
     nope_head_dim: int
     v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """DeepSeek's YaRN RoPE scaling (``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +71,7 @@ class ArchConfig:
     rope_type: str = "rope"       # rope | mrope | none
     rope_theta: float = 1e4
     rope_fraction: float = 1.0
+    rope_scaling: Optional[YarnScaling] = None   # MLA's rope half only
     mrope_sections: Tuple[int, ...] = ()
     sliding_window: Optional[int] = None
     global_layers: Tuple[int, ...] = ()   # layer indices using global attn
@@ -101,7 +120,7 @@ LONG_CONTEXT_ARCHS = ("hymba-1.5b", "h2o-danube-1.8b", "rwkv6-7b")
 ARCH_IDS = (
     "hymba-1.5b", "glm4-9b", "deepseek-coder-33b", "internlm2-20b",
     "h2o-danube-1.8b", "olmoe-1b-7b", "deepseek-v2-236b", "rwkv6-7b",
-    "seamless-m4t-large-v2", "qwen2-vl-7b",
+    "seamless-m4t-large-v2", "qwen2-vl-7b", "deepseek-v2-lite",
 )
 
 _MODULES = {
@@ -115,6 +134,7 @@ _MODULES = {
     "rwkv6-7b": "rwkv6_7b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "qwen2-vl-7b": "qwen2_vl_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

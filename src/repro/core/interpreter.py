@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax._src import core as jcore
+from jax._src import source_info_util
 
 from repro.core.formats import parse_format
 from repro.core.policy import TruncationPolicy, TruncationRule, join_stack
@@ -251,6 +252,15 @@ def quantized_callable(closed: jcore.ClosedJaxpr, out_tree,
     return _jit_sharded(run, flat_shardings)
 
 
+def rebinding_scope(eqn):
+    """Rebind ``eqn`` under its own name stack, joined to the one it is
+    traced in: the compiled program then keeps each op's scopes in its
+    metadata, and a Pallas kernel, whose op is named after the innermost
+    scope, keeps its name."""
+    return source_info_util.set_name_stack(
+        source_info_util.current_name_stack() + eqn.source_info.name_stack)
+
+
 def eval_quantized(jaxpr: jcore.Jaxpr, consts: Sequence[Any], args: Sequence[Any],
                    policy: TruncationPolicy, impl: str = "auto",
                    prefix: str = "", *, native_fp8: bool = False) -> List[Any]:
@@ -281,10 +291,12 @@ def _eval(jaxpr: jcore.Jaxpr, consts: Sequence[Any], args: Sequence[Any],
         prim = eqn.primitive
         name_stack = join_stack(prefix, str(eqn.source_info.name_stack))
         handler = _HOP_HANDLERS.get(prim.name)
-        if handler is not None:
-            outvals = handler(eqn, invals, ctx, name_stack)
-        else:
-            outvals = ctx.eqn_outputs(jaxpr, eqn_idx, eqn, invals, name_stack)
+        with rebinding_scope(eqn):
+            if handler is not None:
+                outvals = handler(eqn, invals, ctx, name_stack)
+            else:
+                outvals = ctx.eqn_outputs(jaxpr, eqn_idx, eqn, invals,
+                                          name_stack)
         if not isinstance(outvals, (list, tuple)):
             outvals = [outvals]
         _safe_map(write, eqn.outvars, outvals)

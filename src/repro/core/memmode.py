@@ -43,6 +43,7 @@ from jax import lax
 from jax._src import core as jcore
 from jax._src import source_info_util
 
+from repro.core.interpreter import rebinding_scope
 from repro.core.policy import TruncationPolicy, join_stack
 from repro.kernels.quantize_em.ops import quantize
 
@@ -407,30 +408,31 @@ def _eval(jaxpr, consts, low_args, shadow_args, policy, threshold, impl,
         shadows = [p[1] for p in pairs]
         prim = eqn.primitive
         ns = join_stack(prefix, str(eqn.source_info.name_stack))
-        handler = _MEM_HOPS.get(prim.name)
-        if handler is not None:
-            louts, shouts, stats = handler(eqn, lows, shadows, policy,
-                                           threshold, impl, rec, stats, ns,
-                                           depth)
-        else:
-            louts = prim.bind(*lows, **eqn.params)
-            shouts = prim.bind(*shadows, **eqn.params)
-            if not prim.multiple_results:
-                louts, shouts = [louts], [shouts]
-            louts, shouts = list(louts), list(shouts)
-            for i, var in enumerate(eqn.outvars):
-                aval = var.aval
-                if not hasattr(aval, "dtype"):
-                    continue
-                rule = policy.rule_for(ns, prim.name, aval.dtype)
-                if rule is not None and jnp.issubdtype(aval.dtype, jnp.floating):
-                    q = quantize(louts[i], rule.fmt, impl=impl)
-                    if rule.mask is not None:
-                        q = jnp.where(rule.mask(louts[i]), q, louts[i])
-                    louts[i] = q
-                    idx = rec.loc_id(_loc_desc(eqn, prefix))
-                    stats = _accumulate(stats, idx, q, shouts[i], threshold,
-                                        rec.traj_col(idx))
+        with rebinding_scope(eqn):
+            handler = _MEM_HOPS.get(prim.name)
+            if handler is not None:
+                louts, shouts, stats = handler(eqn, lows, shadows, policy,
+                                               threshold, impl, rec, stats, ns,
+                                               depth)
+            else:
+                louts = prim.bind(*lows, **eqn.params)
+                shouts = prim.bind(*shadows, **eqn.params)
+                if not prim.multiple_results:
+                    louts, shouts = [louts], [shouts]
+                louts, shouts = list(louts), list(shouts)
+                for i, var in enumerate(eqn.outvars):
+                    aval = var.aval
+                    if not hasattr(aval, "dtype"):
+                        continue
+                    rule = policy.rule_for(ns, prim.name, aval.dtype)
+                    if rule is not None and jnp.issubdtype(aval.dtype, jnp.floating):
+                        q = quantize(louts[i], rule.fmt, impl=impl)
+                        if rule.mask is not None:
+                            q = jnp.where(rule.mask(louts[i]), q, louts[i])
+                        louts[i] = q
+                        idx = rec.loc_id(_loc_desc(eqn, prefix))
+                        stats = _accumulate(stats, idx, q, shouts[i], threshold,
+                                            rec.traj_col(idx))
         for var, lo, sh in zip(eqn.outvars, louts, shouts):
             write(var, lo, sh)
 

@@ -64,8 +64,8 @@ def analyze(rec: Dict) -> Dict:
 
 
 _ADVICE = {
-    ("compute",): "raise useful-FLOP ratio (less remat recompute, tighter "
-                  "capacity factor, fp8 matmul inputs)",
+    ("compute",): "raise useful-FLOP ratio (less remat recompute, fp8 "
+                  "matmul inputs)",
     ("memory",): "cut bytes: fuse elementwise chains, larger microbatch, "
                  "bf16 collectives/state, ring SWA cache",
     ("collective",): "reshard: keep FSDP gathers off the critical path, "

@@ -376,12 +376,16 @@ def gqa_init_cache(cfg: ArchConfig, batch: int, seq_len: int, dtype,
 def mla_param_defs(cfg: ArchConfig) -> dict:
     m = cfg.mla
     d, H = cfg.d_model, cfg.n_heads
+    qk = m.nope_head_dim + m.rope_head_dim
     o_scale = 0.02 / math.sqrt(2 * cfg.n_layers)
+    if m.q_lora is None:
+        q = {"q_proj": ParamDef((d, H * qk), ("embed", "heads"))}
+    else:
+        q = {"q_down": ParamDef((d, m.q_lora), ("embed", None)),
+             "q_norm": ParamDef((m.q_lora,), (None,), init="ones"),
+             "q_up": ParamDef((m.q_lora, H * qk), (None, "heads"))}
     return {
-        "q_down": ParamDef((d, m.q_lora), ("embed", None)),
-        "q_norm": ParamDef((m.q_lora,), (None,), init="ones"),
-        "q_up": ParamDef((m.q_lora, H * (m.nope_head_dim + m.rope_head_dim)),
-                         (None, "heads")),
+        **q,
         "kv_down": ParamDef((d, m.kv_lora + m.rope_head_dim), ("embed", None)),
         "kv_norm": ParamDef((m.kv_lora,), (None,), init="ones"),
         "kv_up": ParamDef((m.kv_lora, H * (m.nope_head_dim + m.v_head_dim)),
@@ -390,16 +394,36 @@ def mla_param_defs(cfg: ArchConfig) -> dict:
     }
 
 
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """1/sqrt(q head size), times YaRN's mscale(factor, mscale_all_dim)^2
+    where the configuration scales its RoPE (DeepSeek-V2's softmax_scale)."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    sc = cfg.rope_scaling
+    if sc is not None and sc.mscale_all_dim:
+        scale *= common.yarn_mscale(sc.factor, sc.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x, positions, cfg):
+    return common.apply_rope(x, positions, theta=cfg.rope_theta,
+                             scaling=cfg.rope_scaling)
+
+
 def _mla_q(p, x, cfg, positions):
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
-    cq = common.rmsnorm(x @ p["q_down"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
-    q = (cq @ p["q_up"].astype(x.dtype)).reshape(
-        B, S, H, m.nope_head_dim + m.rope_head_dim).transpose(0, 2, 1, 3)
+    if m.q_lora is None:
+        q = x @ p["q_proj"].astype(x.dtype)
+    else:
+        cq = common.rmsnorm(x @ p["q_down"].astype(x.dtype), p["q_norm"],
+                            cfg.norm_eps)
+        q = cq @ p["q_up"].astype(x.dtype)
+    q = q.reshape(B, S, H, m.nope_head_dim + m.rope_head_dim).transpose(
+        0, 2, 1, 3)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = common.apply_rope(q_rope, positions, theta=cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(q_rope, positions, cfg)
 
 
 def _mla_latent(p, x, cfg, positions):
@@ -407,8 +431,7 @@ def _mla_latent(p, x, cfg, positions):
     kv = x @ p["kv_down"].astype(x.dtype)
     c_kv, k_rope = kv[..., :m.kv_lora], kv[..., m.kv_lora:]
     c_kv = common.rmsnorm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = common.apply_rope(k_rope[:, None], positions,
-                               theta=cfg.rope_theta)[:, 0]
+    k_rope = _mla_rope(k_rope[:, None], positions, cfg)[:, 0]
     return c_kv, k_rope          # (B,S,kv_lora), (B,S,rope_dim)
 
 
@@ -431,49 +454,118 @@ def mla_forward(p, x, cfg: ArchConfig, *, positions):
         k = constrain(k, "batch", "heads", "seq", None)
         v = constrain(v, "batch", "heads", "seq", None)
     with jax.named_scope("mla_mix"):
-        scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
-        o = flash_attention(q, k, v, causal=True, scale=scale)
+        o = flash_attention(q, k, v, causal=True,
+                            scale=mla_softmax_scale(cfg))
     with jax.named_scope("mla_proj"):
         o = o.transpose(0, 2, 1, 3).reshape(B, S, H * m.v_head_dim)
         out = o @ p["wo"].astype(x.dtype)
     return out, (c_kv, k_rope)
 
 
-def mla_decode(p, x1, cache, pos, cfg: ArchConfig):
-    """Absorbed-form decode: cache holds only (c_kv, k_rope). pos: scalar
-    or (B,) per-slot cursor, matching ``gqa_decode``."""
+def mla_decode_rows(p, x1, cache, pos, cfg: ArchConfig):
+    """Absorbed-form decode that reads the layer's latent cache without
+    writing it: attention over the cache's first ``pos`` rows plus the new
+    token's own row (none for a cursor past the end), as
+    ``decode_attention_with_new`` does for GQA. x1: (B, 1, d); cache:
+    dict(c_kv=(B,S,kv_lora), k_rope=(B,S,rope_dim)); pos: scalar or (B,).
+    Returns (out (B, 1, d), rows): the new token's latent rows, in the
+    cache dtype, for :func:`mla_write` to store."""
     m = cfg.mla
     B = x1.shape[0]
     H = cfg.n_heads
+    S = cache["c_kv"].shape[-2]
+    dt = cache["c_kv"].dtype
+    f32 = jnp.float32
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos_b[:, None]
-    q_nope, q_rope = _mla_q(p, x1, cfg, positions)     # (B,H,1,dn),(B,H,1,dr)
-    c_new, kr_new = _mla_latent(p, x1, cfg, positions)
-    onehot = jnp.arange(cache["c_kv"].shape[1])[None, :] == pos_b[:, None]
-    c_cache = jnp.where(onehot[..., None],
-                        c_new.astype(cache["c_kv"].dtype), cache["c_kv"])
-    r_cache = jnp.where(onehot[..., None],
-                        kr_new.astype(cache["k_rope"].dtype), cache["k_rope"])
+    with jax.named_scope("mla"):
+        with jax.named_scope("absorb"):
+            q_nope, q_rope = _mla_q(p, x1, cfg, positions)  # (B,H,1,dn|dr)
+            c_new, kr_new = _mla_latent(p, x1, cfg, positions)
+            c_new = c_new[:, 0].astype(dt)                  # (B, kv_lora)
+            kr_new = kr_new[:, 0].astype(cache["k_rope"].dtype)
+            # kv_up columns interleave [nope | v] per head
+            w_up = p["kv_up"].reshape(m.kv_lora, H,
+                                      m.nope_head_dim + m.v_head_dim)
+            w_uk = w_up[..., :m.nope_head_dim]
+            w_uv = w_up[..., m.nope_head_dim:]
+            # absorb W_uk into q: (B,H,dn) x (kv_lora,H,dn) -> (B,H,kv_lora)
+            q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, :, 0].astype(f32),
+                               w_uk.astype(f32)).astype(dt)
+            q_pe = q_rope[:, :, 0].astype(dt)
+        with jax.named_scope("attend"):
+            # the cache is read in its own dtype, accumulated in f32
+            scale = mla_softmax_scale(cfg)
+            s = (jnp.einsum("bhl,bsl->bhs", q_lat, cache["c_kv"],
+                            preferred_element_type=f32)
+                 + jnp.einsum("bhd,bsd->bhs", q_pe, cache["k_rope"],
+                              preferred_element_type=f32)) * scale
+            s_new = (jnp.einsum("bhl,bl->bh", q_lat, c_new,
+                                preferred_element_type=f32)
+                     + jnp.einsum("bhd,bd->bh", q_pe, kr_new,
+                                  preferred_element_type=f32)) * scale
+            valid = jnp.arange(S)[None, None, :] < pos_b[:, None, None]
+            new_valid = (pos_b < S)[:, None]
+            s = jnp.where(valid, s, NEG_INF)
+            s_new = jnp.where(new_valid, s_new, NEG_INF)
+            mx = jnp.maximum(jnp.max(s, axis=-1), s_new)
+            pr = jnp.exp(s - mx[..., None])
+            p_new = jnp.where(new_valid, jnp.exp(s_new - mx), 0.0)
+            denom = jnp.sum(pr, axis=-1) + p_new
+            # the new row's weight is rounded as the cached rows' are
+            ctx = (jnp.einsum("bhs,bsl->bhl", pr.astype(dt), cache["c_kv"],
+                              preferred_element_type=f32)
+                   + p_new.astype(dt).astype(f32)[..., None]
+                   * c_new.astype(f32)[:, None])
+            ctx = ctx / denom[..., None]
+            o = jnp.einsum("bhl,lhd->bhd", ctx, w_uv.astype(f32))
+            out = o.reshape(B, 1, H * m.v_head_dim).astype(x1.dtype) \
+                @ p["wo"].astype(x1.dtype)
+    return out, {"c_kv": c_new, "k_rope": kr_new}
 
-    # kv_up columns interleave [nope | v] per head
-    w_up = p["kv_up"].reshape(m.kv_lora, H, m.nope_head_dim + m.v_head_dim)
-    w_uk = w_up[..., :m.nope_head_dim]
-    w_uv = w_up[..., m.nope_head_dim:]
-    f32 = jnp.float32
-    # absorb W_uk into q: (B,H,dn) x (kv_lora,H,dn) -> (B,H,kv_lora)
-    q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, :, 0].astype(f32),
-                       w_uk.astype(f32))
-    s = jnp.einsum("bhl,bsl->bhs", q_lat, c_cache.astype(f32))
-    s = s + jnp.einsum("bhd,bsd->bhs", q_rope[:, :, 0].astype(f32),
-                       r_cache.astype(f32))
-    s = s / math.sqrt(m.nope_head_dim + m.rope_head_dim)
-    idx = jnp.arange(c_cache.shape[1])
-    s = jnp.where(idx[None, None, :] <= pos_b[:, None, None], s, NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    ctx_lat = jnp.einsum("bhs,bsl->bhl", pr, c_cache.astype(f32))
-    o = jnp.einsum("bhl,lhd->bhd", ctx_lat, w_uv.astype(f32))
-    out = o.reshape(B, 1, H * m.v_head_dim).astype(x1.dtype) @ p["wo"].astype(x1.dtype)
-    return out, {"c_kv": c_cache, "k_rope": r_cache}
+
+def mla_write(cache, rows, pos):
+    """Write each lane's new latent rows into the cache at its cursor: one
+    layer's cache (B,S,D) with rows (B,D), or the stacked (L,B,S,D) cache
+    with every layer's rows (L,B,D), for both leaves. A cursor past the
+    cache end writes nothing (dead serving lanes).
+
+    In place when the cache is donated: one ``dynamic_update_slice`` a lane
+    and leaf. ``c_kv`` (512 wide) keeps the sequence on sublanes on TPU, so
+    a row is one sublane of its tiles; ``k_rope`` (64 wide) keeps the
+    sequence minor, so a row is one lane of four tiles a layer. Under a
+    mesh a select over the cache, which stays local to each shard, as in
+    :func:`gqa_write`."""
+    with jax.named_scope("mla"), jax.named_scope("write"):
+        c0 = cache["c_kv"]
+        ax = c0.ndim - 3                     # the batch axis
+        B, S = c0.shape[ax], c0.shape[-2]
+        pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+        if mesh_active():
+            hit = (jnp.arange(S) == pos_b[:, None])[..., None]
+            return {n: jnp.where(hit, jnp.expand_dims(rows[n], -2), cache[n])
+                    for n in ("c_kv", "k_rope")}
+        dead = (pos_b >= S)[:, None]
+        out = {}
+        for n in ("c_kv", "k_rope"):
+            c = cache[n]
+            # the write clamps a cursor past the end to the last row:
+            # write back what that row holds
+            r = jnp.where(dead, c[..., S - 1, :], rows[n])
+            for b in range(B):
+                upd = jnp.expand_dims(lax.index_in_dim(r, b, ax), -2)
+                c = lax.dynamic_update_slice(c, upd,
+                                             (0,) * ax + (b, pos_b[b], 0))
+            out[n] = c
+        return out
+
+
+def mla_decode(p, x1, cache, pos, cfg: ArchConfig):
+    """Absorbed-form decode: cache holds only (c_kv, k_rope). pos: scalar
+    or (B,) per-slot cursor, matching ``gqa_decode``. Returns (out, the
+    written layer cache)."""
+    out, rows = mla_decode_rows(p, x1, cache, pos, cfg)
+    return out, mla_write(cache, rows, pos)
 
 
 def mla_init_cache(cfg: ArchConfig, batch: int, seq_len: int, dtype):

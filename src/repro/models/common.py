@@ -99,9 +99,43 @@ ACTIVATIONS = {
 # RoPE family: standard, partial, and M-RoPE (Qwen2-VL)
 # --------------------------------------------------------------------------
 
-def rope_freqs(rotary_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+def rope_freqs(rotary_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies of the rotary pairs; with ``scaling`` (a
+    :class:`~repro.configs.base.YarnScaling`) DeepSeek's YaRN blend of the
+    original and the interpolated frequencies."""
+    base = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
                             / rotary_dim))
+    if scaling is None:
+        return base
+    low, high = yarn_correction_range(scaling, rotary_dim, theta)
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    # ramp 0 keeps a pair's original frequency, 1 divides it by the factor
+    return base / scaling.factor * ramp + base * (1.0 - ramp)
+
+
+def yarn_correction_range(scaling, rotary_dim: int, theta: float):
+    """The pairs over which YaRN's blend ramps: from the one that turns
+    ``beta_fast`` times over the original context to the one that turns
+    ``beta_slow`` times, clamped to the pairs there are."""
+    def dim(rotations):
+        return (rotary_dim * math.log(scaling.original_max_position_embeddings
+                                      / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(scaling.beta_fast)), 0),
+            min(math.ceil(dim(scaling.beta_slow)), rotary_dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_scale(scaling) -> float:
+    """YaRN's factor on cos and sin (1 where mscale == mscale_all_dim)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
 
 
 def _rotate(x, sin, cos):
@@ -109,17 +143,21 @@ def _rotate(x, sin, cos):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def apply_rope(x, positions, *, theta: float = 1e4, fraction: float = 1.0):
+def apply_rope(x, positions, *, theta: float = 1e4, fraction: float = 1.0,
+               scaling=None):
     """x: (B, H, S, D); positions: (B, S) int. Rotary applied to the first
-    ``fraction`` of D (GLM-4 uses 0.5)."""
+    ``fraction`` of D (GLM-4 uses 0.5); ``scaling``: YaRN (MLA)."""
     d = x.shape[-1]
     rd = int(d * fraction)
     rd -= rd % 2
     if rd == 0:
         return x
-    inv = rope_freqs(rd, theta)                                  # (rd/2,)
+    inv = rope_freqs(rd, theta, scaling)                         # (rd/2,)
     ang = positions.astype(jnp.float32)[:, None, :, None] * inv  # (B,1,S,rd/2)
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    m = rope_scale(scaling)
+    if m != 1.0:
+        sin, cos = sin * m, cos * m
     xr, xp = x[..., :rd], x[..., rd:]
     xr = _rotate(xr.astype(jnp.float32), sin, cos).astype(x.dtype)
     return jnp.concatenate([xr, xp], axis=-1) if rd < d else xr

@@ -45,8 +45,11 @@ class Model:
         mc = cfg.moe
         n_stack = cfg.n_layers - mc.first_k_dense
         per_expert = 3 * cfg.d_model * mc.d_expert  # swiglu wi(2x) + wo
-        inactive = n_stack * (mc.n_experts - mc.top_k) * per_expert
-        return total - inactive
+        # of the experts held here, a token uses top_k x held / experts
+        n_held = len(mc.held)
+        inactive = n_stack * (n_held - mc.top_k * n_held / mc.n_experts) \
+            * per_expert
+        return int(total - inactive)
 
     # ---- execution --------------------------------------------------------
     def loss(self, params, batch):

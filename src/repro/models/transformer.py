@@ -2,7 +2,8 @@
 
 One generic implementation hosts all assigned decoder families:
   * dense GQA (glm4, deepseek-coder, internlm2, h2o-danube/SWA, qwen2-vl/M-RoPE)
-  * MoE (olmoe; deepseek-v2 with MLA + shared experts + leading dense layers)
+  * MoE (olmoe; deepseek-v2 and -v2-lite with MLA + shared experts + leading
+    dense layers; a layer may hold a share of the experts, see models/moe.py)
   * hybrid (hymba: parallel GQA-SWA + Mamba heads per layer, 3 global layers)
   * attn-free (rwkv6: time-mix + channel-mix)
 
@@ -111,8 +112,9 @@ def _seq_mix(cfg: ArchConfig, p, x, positions, is_global, mix_state,
     ``is_global=True`` lifts the sliding window (global-attention layers are
     executed as their own unrolled segments so the window stays static and
     the flash path can skip out-of-window KV blocks). ``rows_only`` (GQA
-    decode): leave the cache unwritten and return the new token's K/V rows
-    in place of the new state, for one stacked write after the scan."""
+    and MLA decode): leave the cache unwritten and return the new token's
+    K/V (or latent) rows in place of the new state, for one stacked write
+    after the scan."""
     if cfg.attn_type == "gqa":
         window = None if is_global else cfg.sliding_window
         if decode:
@@ -127,7 +129,9 @@ def _seq_mix(cfg: ArchConfig, p, x, positions, is_global, mix_state,
 
     if cfg.attn_type == "mla":
         if decode:
-            return attention.mla_decode(p["attn"], x, mix_state, pos, cfg)
+            decode_fn = (attention.mla_decode_rows if rows_only
+                         else attention.mla_decode)
+            return decode_fn(p["attn"], x, mix_state, pos, cfg)
         with jax.named_scope("attn"):
             y, _ = attention.mla_forward(p["attn"], x, cfg, positions=positions)
         return y, mix_state
@@ -173,7 +177,7 @@ def layer_forward(cfg: ArchConfig, p, x, positions, kind: str,
                   is_global=None, mix_state=None, decode: bool = False,
                   pos=None, rows_only: bool = False):
     """One decoder layer. Returns (x, new_mix_state); with ``rows_only``
-    (GQA decode) the new K/V rows take the new state's place."""
+    (GQA and MLA decode) the new cache rows take the new state's place."""
     with jax.named_scope("pre_norm"):
         h = apply_norm(p["norm1"], x, cfg)
     y, new_state = _seq_mix(cfg, p, h, positions, is_global, mix_state,
@@ -379,8 +383,11 @@ def init_layer_cache(cfg: ArchConfig, batch: int, seq_len: int, dtype,
 
 
 def _stack_caches(one, n):
-    return jax.tree_util.tree_map(
-        lambda t: jnp.broadcast_to(t[None], (n,) + t.shape).copy(), one)
+    """``n`` copies of one layer's cache, stacked in one call, so that the
+    stack is the only buffer made (an eager broadcast and copy would hold
+    the stack twice)."""
+    return jax.jit(lambda one: jax.tree_util.tree_map(
+        lambda t: jnp.broadcast_to(t[None], (n,) + t.shape), one))(one)
 
 
 def init_cache(cfg: ArchConfig, batch: int, seq_len: int):
@@ -456,10 +463,10 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, embeds=None):
         return body
 
     if cfg.scan_layers:
-        # a GQA stack reads its cache inside the scan and writes every
-        # layer's new rows after it, in place when the cache is donated,
-        # instead of a rewrite of each layer's whole cache
-        rows_only = cfg.attn_type == "gqa"
+        # a GQA or MLA stack reads its cache inside the scan and writes
+        # every layer's new rows after it, in place when the cache is
+        # donated, instead of a rewrite of each layer's whole cache
+        rows_only = cfg.attn_type in ("gqa", "mla")
         scan_outs = []
         new_globals = []
         c_off = 0          # cursor into the compacted ring-cache stack
@@ -481,9 +488,11 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, embeds=None):
         new_stack = (jax.tree_util.tree_map(
             lambda *ts: jnp.concatenate(ts, axis=0), *scan_outs)
             if len(scan_outs) > 1 else scan_outs[0])
-        if rows_only:
+        if cfg.attn_type == "gqa":
             new_stack = attention.gqa_write(cache["layers"], new_stack, pos,
                                             window=cfg.sliding_window)
+        elif cfg.attn_type == "mla":
+            new_stack = attention.mla_write(cache["layers"], new_stack, pos)
         if new_globals:
             new_cache["global"] = new_globals
     else:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core.api import truncate
 from repro.core.policy import resolve_policy
+from repro.distributed.sharding import mesh_active
 from repro.models import Model
 from repro.serving.shadow import ShadowConfig, ShadowProfiler
 
@@ -90,18 +91,8 @@ class Engine:
         # wrapping shared functions (the staticmethod reset, bound decode
         # methods) would alias executable caches across engines and break
         # the one-entry-per-engine assertion
-        # settle steady-state layouts BEFORE counting executables: under a
-        # serving mesh the first decode re-shards the cache, so a cache
-        # whose layout changes between the first and second call would
-        # retrace every jit'd path. One warmup decode through a throwaway
-        # jit wrapper, then re-zero the warmed cache inside jit (keeps the
-        # layout decode settled on) — the real paths below only ever see
-        # steady-state shardings and stay at one executable each.
-        _, warmed = jax.jit(lambda p, c, t, _fn=step: _fn(p, c, t))(
-            params, self.cache, jnp.zeros((batch_size,), jnp.int32))
-        self.cache = jax.tree_util.tree_map(
-            lambda t: jax.device_put(jnp.zeros(t.shape, t.dtype),
-                                     t.sharding), warmed)
+        if mesh_active():
+            self.cache = self._settle(step, params, self.cache)
         # the cache is donated to the decode step and to the slot reset, so
         # that each writes its rows in place instead of copying the cache
         self._decode = jax.jit(lambda p, c, t, _fn=step: _fn(p, c, t),
@@ -117,6 +108,31 @@ class Engine:
         self._finished: deque = deque()
         self._next_rid = 0
         self._tick = 0
+        # static counters of the dispatch span: the routed experts each MoE
+        # layer holds here, and the bytes of an MLA latent cache
+        cfg = model.cfg
+        self._counters = {
+            "experts_held": len(cfg.moe.held) if cfg.moe else 0,
+            "latent_cache_bytes": sum(
+                t.nbytes for t in jax.tree_util.tree_leaves(
+                    {k: v for k, v in self.cache.items() if k != "pos"})
+            ) if cfg.attn_type == "mla" else 0}
+
+    def _settle(self, step, params, cache):
+        """The cache in the layout the decode step settles on. Under a
+        serving mesh the first decode re-shards the cache, so a cache whose
+        layout changed between the first and second call would retrace
+        every jit'd path: one warm-up decode through a throwaway jit, then
+        the warmed cache zeroed in its own layout. Both take the cache
+        donated, so set-up never holds a second cache."""
+        warm = jax.jit(lambda p, c, t, _fn=step: _fn(p, c, t)[1],
+                       donate_argnums=(1,))
+        warmed = warm(params, cache, jnp.zeros((self.B,), jnp.int32))
+        zero = jax.jit(lambda c: jax.tree_util.tree_map(jnp.zeros_like, c),
+                       donate_argnums=(0,),
+                       out_shardings=jax.tree_util.tree_map(
+                           lambda t: t.sharding, warmed))
+        return zero(warmed)
 
     # ---- request management ------------------------------------------------
     def submit(self, prompt=None, _legacy_prompt=None, *,
@@ -248,7 +264,7 @@ class Engine:
             with jax.profiler.TraceAnnotation(
                     "raptor/engine.dispatch",
                     path="shadow" if shadowed else "decode", live=len(live),
-                    emitting=len(emitting), in_place=1):
+                    emitting=len(emitting), in_place=1, **self._counters):
                 if shadowed:
                     logits, self.cache, report = self._shadow.step(
                         self.params, self.cache, tok)

@@ -38,7 +38,7 @@ def make_batch(cfg, rng):
 
 # heavyweight archs run in the slow (full/CI) tier; the default tier-1 run
 # keeps one dense and one MoE representative (see pytest.ini)
-_FAST_ARCHS = {"h2o-danube-1.8b", "olmoe-1b-7b"}
+_FAST_ARCHS = {"h2o-danube-1.8b", "olmoe-1b-7b", "deepseek-v2-lite"}
 _ARCH_PARAMS = [
     a if a in _FAST_ARCHS else pytest.param(a, marks=pytest.mark.slow)
     for a in ARCH_IDS

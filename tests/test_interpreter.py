@@ -199,3 +199,45 @@ def test_scoped_policy_survives_grad():
     g_miss = truncate(jax.grad(loss),
                       TruncationPolicy.scoped("nothing", E5M2))(w, x)
     np.testing.assert_array_equal(np.asarray(g_full), np.asarray(g_miss))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("transform", ["truncate", "memtrace"])
+def test_rebinding_keeps_scopes_and_kernel_names(transform):
+    """Each equation is rebound under its own name stack: ops keep their
+    scopes (inside a scan too), and a Pallas kernel, whose compiled op is
+    named after its innermost scope, keeps its name."""
+    from repro.core import memtrace
+    from repro.kernels import kv_write
+
+    def f(x, k, rows):
+        with jax.named_scope("outer"):
+            def body(c, xi):
+                with jax.named_scope("mlp"):
+                    return c + xi * 2.0, None
+            y, _ = lax.scan(body, x[0], x)
+        k, _ = kv_write.kv_write(k, k, rows, rows, jnp.zeros((1,), jnp.int32),
+                                 interpret=True)
+        return y, k
+
+    pol = TruncationPolicy.scoped("**/mlp", E5M2)
+    args = (jnp.ones((3, 4), jnp.float32), jnp.zeros((1, 1, 1, 8, 128)),
+            jnp.ones((1, 1, 1, 8)))
+    fn = truncate(f, pol) if transform == "truncate" else \
+        memtrace(f, pol, threshold=1e-3)
+    stacks = {(e.primitive.name, str(e.source_info.name_stack))
+              for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)}
+    assert any(p == "pallas_call" and s.endswith("kv_write")
+               for p, s in stacks), stacks
+    assert any(p == "mul" and s.endswith("mlp") for p, s in stacks), stacks
+    assert any(p == "scan" and s.endswith("outer") for p, s in stacks), stacks

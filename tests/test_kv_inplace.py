@@ -1,12 +1,13 @@
 """The serving KV cache is written in place.
 
-``transformer.decode_step`` reads a GQA stack's cache inside the layer
-scan and writes every layer's new rows after it; ``gqa_decode`` writes one
-layer's cache the same way. Both are held here to the algorithm they
-replaced, kept below as the reference: a one-hot ``where`` that rewrites
-each layer's whole cache, carried through the scan as ``xs``/``ys``, and
-attention over the written cache. Then the engine's compiled decode step
-and slot reset alias the donated cache and rewrite none of it.
+``transformer.decode_step`` reads a GQA or MLA stack's cache inside the
+layer scan and writes every layer's new rows after it; ``gqa_decode`` and
+``mla_decode`` write one layer's cache the same way. Both are held here to
+the algorithm they replaced, kept below as the reference: a one-hot
+``where`` that rewrites each layer's whole cache, carried through the scan
+as ``xs``/``ys``, and attention over the written cache. Then the engine's
+compiled decode step and slot reset alias the donated cache and rewrite
+none of it, and set-up holds one cache.
 """
 import functools
 import math
@@ -71,6 +72,64 @@ def onehot_gqa_decode(p, x1, cache, pos, cfg, *, window=None,
                                                             "v": v_cache}
 
 
+def onehot_mla_decode(p, x1, cache, pos, cfg):
+    """MLA decode as it was: the new latent rows written one-hot into the
+    whole layer cache, then attention over the written cache, its products
+    in the cache's dtype with float32 sums, as the program reads it."""
+    m = cfg.mla
+    B, H = x1.shape[0], cfg.n_heads
+    S = cache["c_kv"].shape[1]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q_nope, q_rope = attention._mla_q(p, x1, cfg, pos_b[:, None])
+    c_new, kr_new = attention._mla_latent(p, x1, cfg, pos_b[:, None])
+    onehot = (jnp.arange(S)[None, :] == pos_b[:, None])[..., None]
+    c_cache = jnp.where(onehot, c_new.astype(cache["c_kv"].dtype),
+                        cache["c_kv"])
+    r_cache = jnp.where(onehot, kr_new.astype(cache["k_rope"].dtype),
+                        cache["k_rope"])
+    w_up = p["kv_up"].reshape(m.kv_lora, H, m.nope_head_dim + m.v_head_dim)
+    f32, dt = jnp.float32, c_cache.dtype
+    q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, :, 0].astype(f32),
+                       w_up[..., :m.nope_head_dim].astype(f32)).astype(dt)
+    s = (jnp.einsum("bhl,bsl->bhs", q_lat, c_cache,
+                    preferred_element_type=f32)
+         + jnp.einsum("bhd,bsd->bhs", q_rope[:, :, 0].astype(dt), r_cache,
+                      preferred_element_type=f32)
+         ) * attention.mla_softmax_scale(cfg)
+    s = jnp.where(jnp.arange(S)[None, None, :] <= pos_b[:, None, None], s,
+                  attention.NEG_INF)
+    pr = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    ctx = jnp.einsum("bhs,bsl->bhl", pr.astype(dt), c_cache,
+                     preferred_element_type=f32) / jnp.sum(pr, -1)[..., None]
+    o = jnp.einsum("bhl,lhd->bhd", ctx,
+                   w_up[..., m.nope_head_dim:].astype(f32))
+    out = o.reshape(B, 1, -1).astype(x1.dtype) @ p["wo"].astype(x1.dtype)
+    return out, {"c_kv": c_cache, "k_rope": r_cache}
+
+
+def reference_mla_decode_step(params, cache, tokens, cfg):
+    """An MLA stack's decode step as it was: the lead layer and every
+    scanned layer rewrite their whole latent cache one-hot."""
+    pos = cache["pos"]
+    x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens][:, None]
+    lead = []
+    for p_l, c_l in zip(params["lead_layers"], cache["lead"]):
+        x, st = layer_forward(cfg, p_l, x, None, "dense_lead",
+                              mix_state=c_l, decode=True, pos=pos)
+        lead.append(st)
+
+    def body(x, xs):
+        p_l, c_l = xs
+        return layer_forward(cfg, p_l, x, None, "moe", is_global=False,
+                             mix_state=c_l, decode=True, pos=pos)
+
+    x, stack = lax.scan(body, x, (params["layers"], cache["layers"]))
+    x = apply_norm(params["final_norm"], x, cfg)
+    logits = x[:, 0].astype(jnp.float32) @ params["lm_head"].astype(
+        jnp.float32)
+    return logits, {"pos": pos + 1, "layers": stack, "lead": lead}
+
+
 def reference_decode_step(params, cache, tokens, cfg):
     """A dense GQA stack's decode step as it was: the cache rides the scan
     as ``xs`` and every layer's rewritten cache comes back as ``ys``."""
@@ -95,6 +154,11 @@ def reference_decode_step(params, cache, tokens, cfg):
 
 def _dense(**kw):
     return get_config("h2o-danube-1.8b", "smoke").replace(
+        dtype="bfloat16", **kw)
+
+
+def _mla(**kw):
+    return get_config("deepseek-v2-lite", "smoke").replace(
         dtype="bfloat16", **kw)
 
 
@@ -136,6 +200,13 @@ CASES = {
     # one layer's cache at a time
     "kernel_encdec": (get_config("seamless-m4t-large-v2", "smoke").replace(
         dtype="bfloat16"), 2, 128, [3, 126]),
+    # MLA latent cache (lead layer and stack); slot 3 starts at the last
+    # position and runs past the end
+    "mla_ragged": (_mla(), 4, 12, [0, 3, 7, 11]),
+    # slot 1 is past the cache end from the start, slot 3 runs past it
+    "mla_dead_lane": (_mla(), 4, 12, [2, 12, 20, 9]),
+    # the same under a mesh, where the write is a select over the cache
+    "mla_dead_lane_under_mesh": (_mla(), 4, 12, [2, 12, 20, 9]),
 }
 
 
@@ -172,8 +243,13 @@ def test_decode_step_matches_onehot_reference(case, monkeypatch):
     # encdec and hymba keep calling gqa_decode: their reference is their
     # own step with the one-hot write in its place
     monkeypatch.setattr(attention, "gqa_decode", onehot_gqa_decode)
-    ref_step = (model.decode_step if cfg.family != "dense" else
-                lambda p, c, t: reference_decode_step(p, c, t, cfg))
+    monkeypatch.setattr(attention, "mla_decode", onehot_mla_decode)
+    if cfg.attn_type == "mla":
+        ref_step = lambda p, c, t: reference_mla_decode_step(p, c, t, cfg)
+    elif cfg.family == "dense":
+        ref_step = lambda p, c, t: reference_decode_step(p, c, t, cfg)
+    else:
+        ref_step = model.decode_step
     want = _run(jax.jit(ref_step), params, cache, toks)
 
     for t in range(TICKS):
@@ -297,3 +373,42 @@ def test_reset_clears_nan_left_in_a_lane(served):
     eng.run()
     assert got.status == "ok"
     assert got.out_tokens == want.out_tokens
+
+
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "deepseek-v2-lite"])
+def test_setup_holds_one_cache(arch):
+    """After ``Engine(...)`` one buffer of each cache leaf is live: set-up
+    warms nothing up through a second cache (under a mesh it warms up
+    through a throwaway step that takes the cache donated)."""
+    cfg = get_config(arch, "smoke").replace(dtype="bfloat16")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(9))
+    B, S = 3, 37                   # shapes no weight has
+    eng = Engine(model, params, batch_size=B, max_seq_len=S,
+                 policy=TruncationPolicy.scoped("**/mlp", "e5m7"))
+    leaves = jax.tree_util.tree_leaves(eng.cache)
+    live = [a for a in jax.live_arrays() if not a.is_deleted()]
+    for leaf in leaves:
+        same = [a for a in live if a.shape == leaf.shape
+                and a.dtype == leaf.dtype]
+        # lead layers' caches share a shape: one buffer each
+        want = sum(t.shape == leaf.shape and t.dtype == leaf.dtype
+                   for t in leaves)
+        assert len(same) == want, (leaf.shape, len(same))
+    eng.submit(np.arange(1, 4, dtype=np.int32), max_new_tokens=2)
+    eng.run()
+    assert eng.cache_sizes()["decode"] == 1
+
+
+def test_settle_warms_up_in_place(served):
+    """The warm-up that a serving mesh needs takes the cache donated and
+    zeroes it in its own buffers' layout."""
+    cfg, model, params = served
+    eng = Engine(model, params, batch_size=2, max_seq_len=16)
+    cache = jax.tree_util.tree_map(lambda t: t + 1, eng.cache)
+    out = eng._settle(model.decode_step, params, cache)
+    assert all(t.is_deleted() for t in jax.tree_util.tree_leaves(cache))
+    for t in jax.tree_util.tree_leaves(out):
+        assert not np.any(np.asarray(t))
+    assert jax.tree_util.tree_map(lambda t: t.shape, out) == \
+        jax.tree_util.tree_map(lambda t: t.shape, eng.cache)

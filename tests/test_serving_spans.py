@@ -108,6 +108,18 @@ def test_in_place_on_every_tick(traced):
     assert flags == {"decode": {1}, "shadow": {1}}
 
 
+def test_dispatch_counts_experts_and_latent_cache(traced):
+    """The static ``experts_held`` and ``latent_cache_bytes`` ride every
+    dispatch; a dense GQA model holds neither."""
+    _, _, tr = traced
+    seen = set()
+    for _, children in tr.trees("raptor/engine.step"):
+        for c in children:
+            if c[2] == "raptor/engine.dispatch":
+                seen.add((c[3]["experts_held"], c[3]["latent_cache_bytes"]))
+    assert seen == {(0, 0)}
+
+
 def test_emitted_counts_the_tokens_served(traced):
     _, reqs, tr = traced
     counts = {"emitted": 0, "finished": 0, "quarantined": 0}

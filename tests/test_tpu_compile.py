@@ -135,3 +135,74 @@ def test_danube_decode_writes_kv_cache_in_place(spec, monkeypatch, kernel):
     else:
         assert ops == {"parameter", "get-tuple-element",
                        "dynamic-update-slice"}, ops
+
+
+def _shapes(spec, tree):
+    return jax.tree_util.tree_map(lambda t: spec(t.shape, t.dtype), tree)
+
+
+def test_kernel_keeps_its_name_under_a_policy(spec, monkeypatch):
+    """Under a truncation policy the interpreter rebinds the ``kv_write``
+    kernel in its own scope, so the compiled program names it
+    ``kv_write`` (not after the enclosing jit), as it does without one."""
+    from repro.configs.base import get_config
+    from repro.core import TruncationPolicy
+    from repro.core.api import truncate
+    from repro.models import Model, attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = get_config("h2o-danube-1.8b", "smoke").replace(
+        n_layers=2, dtype="bfloat16", sliding_window=4096)
+    model = Model(cfg)
+    params = _shapes(spec, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = _shapes(spec, jax.eval_shape(lambda: model.init_cache(4, 256)))
+    step = truncate(model.decode_step,
+                    TruncationPolicy.scoped("**/mlp", "e5m7"), impl="pallas")
+    text = jax.jit(lambda p, c, t: step(p, c, t), donate_argnums=(1,)).lower(
+        params, cache, spec((4,), jnp.int32)).compile().as_text()
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if KERNEL in line and " = " in line]
+    assert any(k.startswith("%kv_write") for k in kernels), kernels
+    assert any(k.startswith("%quantize_em") for k in kernels), kernels
+    assert not any(k.startswith("%_lambda_") for k in kernels), kernels
+
+
+def test_dsv2lite_decode_writes_latent_cache_in_place(spec):
+    """DeepSeek-V2-Lite's decode step at serving width (the dense layer
+    and two MoE layers; 64 slots of 3072) under the cell's policy: with
+    the cache donated, the compiled step hands the latent cache back in
+    its own buffers, holds no temporary as large as one layer's latent
+    cache, and keeps the MLA and MoE scopes in its ops' metadata."""
+    import re
+
+    import numpy as np
+
+    from repro.configs.base import get_config
+    from repro.core import TruncationPolicy
+    from repro.core.api import truncate
+    from repro.models import Model
+
+    import dataclasses
+    cfg = get_config("deepseek-v2-lite").replace(n_layers=3)
+    # one EP8 rank's share of the routed experts, as the chip serves it
+    cfg = cfg.replace(moe=dataclasses.replace(
+        cfg.moe, held_experts=tuple(range(8))))
+    model = Model(cfg)
+    B, S = 64, 3072
+    params = _shapes(spec, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = _shapes(spec, jax.eval_shape(lambda: model.init_cache(B, S)))
+    step = truncate(model.decode_step,
+                    TruncationPolicy.scoped("**/mlp", "e5m7"), impl="pallas")
+    compiled = jax.jit(lambda p, c, t: step(p, c, t),
+                       donate_argnums=(1,)).lower(
+        params, cache, spec((B,), jnp.int32)).compile()
+    cache_bytes = sum(int(np.prod(t.shape)) * t.dtype.itemsize
+                      for t in jax.tree_util.tree_leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    layer_bytes = B * S * (512 + 64) * 2
+    assert mem.temp_size_in_bytes < layer_bytes // 8, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    names = set(re.findall(r'op_name="[^"]*?/((?:mla|moe)/\w+)', text))
+    assert names >= {"mla/absorb", "mla/attend", "mla/write", "moe/router",
+                     "moe/experts", "moe/shared"}, names
