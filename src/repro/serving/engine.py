@@ -32,7 +32,7 @@ from repro.core.api import truncate
 from repro.core.policy import resolve_policy
 from repro.distributed.sharding import mesh_active
 from repro.models import Model
-from repro.serving.shadow import ShadowConfig, ShadowProfiler
+from repro.serving.shadow import ShadowConfig, ShadowProfiler, greedy_pick
 
 
 @dataclasses.dataclass
@@ -94,9 +94,11 @@ class Engine:
         if mesh_active():
             self.cache = self._settle(step, params, self.cache)
         # the cache is donated to the decode step and to the slot reset, so
-        # that each writes its rows in place instead of copying the cache
-        self._decode = jax.jit(lambda p, c, t, _fn=step: _fn(p, c, t),
-                               donate_argnums=(1,))
+        # that each writes its rows in place instead of copying the cache;
+        # the step picks each slot's token on the device after the policy
+        self._decode = jax.jit(
+            lambda p, c, t, _fn=step: greedy_pick(_fn(p, c, t)),
+            donate_argnums=(1,))
         self._reset = jax.jit(lambda c, s, _fn=self._slot_reset: _fn(c, s),
                               donate_argnums=(0,))
         self._shadow: Optional[ShadowProfiler] = None
@@ -266,11 +268,11 @@ class Engine:
                     path="shadow" if shadowed else "decode", live=len(live),
                     emitting=len(emitting), in_place=1, **self._counters):
                 if shadowed:
-                    logits, self.cache, report = self._shadow.step(
+                    _, self.cache, nxt, finite, report = self._shadow.step(
                         self.params, self.cache, tok)
                 else:
-                    logits, self.cache = self._decode(self.params,
-                                                      self.cache, tok)
+                    _, self.cache, nxt, finite = self._decode(
+                        self.params, self.cache, tok)
                 self.assert_zero_recompile()
 
             for s in live:
@@ -279,11 +281,13 @@ class Engine:
                     req._fed += 1
                 self.lengths[s] += 1
 
-            # the tick's outputs go to the host in one round trip: the
-            # logits, and on a shadowed tick the report, which observe merges
-            # on the host. The copies are asked for before the host blocks,
-            # so that the runtime starts them as soon as the step is done.
-            outs = jax.tree_util.tree_leaves((logits, report))
+            # the tick's outputs go to the host in one round trip: each
+            # slot's greedy token and whether its logits were all finite
+            # (the logits stay on the device), and on a shadowed tick the
+            # report, which observe merges on the host. The copies are asked
+            # for before the host blocks, so that the runtime starts them as
+            # soon as the step is done.
+            outs = jax.tree_util.tree_leaves((nxt, finite, report))
             with jax.profiler.TraceAnnotation("raptor/engine.wait"):
                 for x in outs:
                     x.copy_to_host_async()
@@ -291,7 +295,7 @@ class Engine:
             with jax.profiler.TraceAnnotation(
                     "raptor/engine.fetch",
                     bytes=sum(x.nbytes for x in outs)):
-                logits_np, report = jax.device_get((logits, report))
+                nxt, finite, report = jax.device_get((nxt, finite, report))
             if shadowed:
                 with jax.profiler.TraceAnnotation("raptor/shadow.observe",
                                                   requests=len(shadow_live)):
@@ -303,14 +307,11 @@ class Engine:
                 if event is not None and self.artifact is not None:
                     self.artifact = self._shadow.log.attach(self.artifact)
 
-            with jax.profiler.TraceAnnotation("raptor/engine.sample"):
-                nxt = np.argmax(logits_np, axis=-1)
-                # quarantine non-finite decode: a slot whose logits went
-                # NaN/Inf (numerically broken policy, corrupted params)
-                # fails THAT request with a clear status and frees the slot
-                # for the next admission — an argmax over NaN logits would
-                # otherwise silently emit token 0 and poison the stream
-                finite = np.isfinite(logits_np).all(axis=-1)
+            # quarantine non-finite decode: a slot whose logits went NaN/Inf
+            # (numerically broken policy, corrupted params) fails THAT
+            # request with a clear status and frees the slot for the next
+            # admission — an argmax over NaN logits would otherwise silently
+            # emit a garbage token and poison the stream
             with jax.profiler.TraceAnnotation("raptor/engine.emit") as span:
                 emitted = finished = quarantined = 0
                 for s in emitting:

@@ -20,9 +20,22 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.api import memtrace, profile_trajectory
 from repro.guardrails.log import GuardrailLog
+
+
+def greedy_pick(out):
+    """The decode step's ``(logits, cache)`` with, computed from the logits
+    on the device, each slot's greedy token (the first index of its row's
+    maximum, as ``np.argmax`` breaks ties) and whether its row is finite:
+    ``(logits, cache, token, finite)``. The engine's decode step and the
+    shadowed step both end with it, outside the policy, so that a tick
+    copies B token ids and B flags to the host and not the logits."""
+    logits, cache = out
+    return (logits, cache, jnp.argmax(logits, -1).astype(jnp.int32),
+            jnp.isfinite(logits).all(-1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,9 +107,12 @@ class ShadowProfiler:
                              "expected 'memtrace' or 'trajectory'")
         # the cache is donated, as to the engine's decode step, so that the
         # step writes its new rows in place: undonated, it would copy the
-        # whole cache on every shadowed tick
-        self._step = jax.jit(lambda p, c, t, _fn=shadowed: _fn(p, c, t),
-                             donate_argnums=(1,))
+        # whole cache on every shadowed tick. The greedy pick runs on the
+        # truncated lane's logits after the shadowed step, in the same call
+        def step(p, c, t):
+            out, report = shadowed(p, c, t)
+            return greedy_pick(out), report
+        self._step = jax.jit(step, donate_argnums=(1,))
         self.config = config
         self.artifact = artifact
         self._rng = np.random.RandomState(config.seed)
@@ -119,11 +135,12 @@ class ShadowProfiler:
     # ---- the shadowed step -------------------------------------------------
     def step(self, params, cache, tokens):
         """Paired truncated/shadow execution of one decode tick. Returns
-        ``(logits, new_cache, report)`` — logits/cache are the truncated
-        lane, bit-identical to the plain deployed step. ``cache`` is
-        donated: use the returned one."""
-        (logits, new_cache), report = self._step(params, cache, tokens)
-        return logits, new_cache, report
+        ``(logits, new_cache, token, finite, report)`` — the truncated
+        lane, bit-identical to the plain deployed step, with its greedy
+        pick (:func:`greedy_pick`). ``cache`` is donated: use the returned
+        one."""
+        outs, report = self._step(params, cache, tokens)
+        return (*outs, report)
 
     def observe(self, report, shadow_requests: Sequence, tick: int) -> None:
         """Merge one tick's report into the rolling serving report and into
@@ -131,7 +148,7 @@ class ShadowProfiler:
         SUM for flags/op_counts, MAX for max_rel). ``RaptorReport.merge``
         runs on the host with int64 counts, so that a rolling count does
         not wrap at 2^31; the engine hands over a report fetched with the
-        tick's logits."""
+        tick's tokens."""
         rep = getattr(report, "totals", report)   # TrajectoryReport -> totals
         self.report = rep if self.report is None else self.report.merge(rep)
         for req in shadow_requests:

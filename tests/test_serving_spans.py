@@ -26,8 +26,7 @@ from benchmarks.chip import program_spans, xplane  # noqa: E402
 
 PHASES = ["raptor/engine.admit", "raptor/engine.feed",
           "raptor/engine.dispatch", "raptor/engine.wait",
-          "raptor/engine.fetch", "raptor/engine.sample",
-          "raptor/engine.emit"]
+          "raptor/engine.fetch", "raptor/engine.emit"]
 SHADOW = ["raptor/shadow.observe", "raptor/shadow.check"]
 
 
@@ -73,15 +72,16 @@ def test_each_tick_is_one_step_tree(traced):
         (wait,) = [c for c in children if c[2] == "raptor/engine.wait"]
         (fetch,) = [c for c in children if c[2] == "raptor/engine.fetch"]
         assert dispatch[1] <= wait[0] and wait[1] <= fetch[0]
-        # the logits, and on a shadowed tick the report with them
-        logits = 2 * 48 * 4
+        # each slot's token id and finite flag, and on a shadowed tick the
+        # report with them; never the logits
+        picks = 2 * (4 + 1)
         if dispatch[3]["path"] == "shadow":
             (observe,) = [c for c in children
                           if c[2] == "raptor/shadow.observe"]
             assert fetch[1] <= observe[0]
-            assert fetch[3]["bytes"] > logits
+            assert fetch[3]["bytes"] > picks
         else:
-            assert fetch[3]["bytes"] == logits
+            assert fetch[3]["bytes"] == picks
 
 
 def test_shadow_spans_exactly_on_shadowed_ticks(traced):
